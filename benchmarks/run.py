@@ -19,6 +19,8 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
     only = set(args.only.split(",")) if args.only else None
+    from repro.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
 
     sections = []
     if only is None or "quality" in only:

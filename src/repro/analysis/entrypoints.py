@@ -176,9 +176,10 @@ def _build_engine(strategy: str, *, capture: bool = False):
     from repro.train.train_step import dnn_ssl_grads
 
     cfg, params, hyper, opt = _tiny_problem()
+    mesh = data_mesh(1) if strategy == "sync_mesh" else None
 
     def grad_fn(p, batch):
-        return dnn_ssl_grads(p, batch, cfg=cfg, hyper=hyper)
+        return dnn_ssl_grads(p, batch, cfg=cfg, hyper=hyper, mesh=mesh)
 
     def step_fn(state, batch, lr):
         # fold_in, not split: the carried key advances per step without a
@@ -192,8 +193,8 @@ def _build_engine(strategy: str, *, capture: bool = False):
                                    step=state.step + 1), metrics
 
     kwargs = dict(strategy=strategy)
-    if strategy == "sync_mesh":
-        kwargs["mesh"] = data_mesh(1)
+    if mesh is not None:
+        kwargs["mesh"] = mesh
     if capture:
         kwargs["capture_fn"] = lambda p, b: dnn_hidden(
             p, b["x"].reshape(-1, cfg.input_dim))

@@ -20,7 +20,7 @@ registered entry point (no execution) and enforces exactly that:
     bodies (a ``debug_print`` in the engine's scan body would serialize
     every step on a host round-trip);
   * ``J005`` — the engine's chunk jit must donate every carry leaf
-    (``donated_invars`` of the named pjit eqn);
+    (``donated_invars`` of the named jit call);
   * ``J006`` — large arrays captured as jaxpr *constants* (closure
     capture silently bakes weights into the executable and re-traces on
     every new array identity) instead of arriving as arguments.
@@ -45,12 +45,14 @@ __all__ = [
     "audit_entry",
     "trace_entry",
     "iter_eqns",
+    "is_jit_call",
 ]
 
 #: Primitives that imply a host round-trip or synchronization; inside a
 #: scan/while body each occurrence stalls the whole compiled loop.
 CALLBACK_PRIMITIVES = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
     "host_callback_call", "outside_call", "infeed", "outfeed",
     "copy_to_host",
 })
@@ -83,7 +85,7 @@ class EntryPoint:
     #: Declared low-precision compute dtype ("bfloat16") for J003, or None.
     compute_dtype: str | None = None
     allow_f64: bool = False
-    #: (pjit name, n leading flat invars that must be donated) for J005;
+    #: (jit name, n leading flat invars that must be donated) for J005;
     #: n=None derives the count from the first build() arg (the carry tree).
     donate: tuple[str, int | None] | None = None
     #: J006 threshold for captured constants.
@@ -97,6 +99,13 @@ class EntryPoint:
     #: Collectives tolerated inside scan/while bodies (S002); reductions
     #: keep their operand shape, gathers do not — hence the default.
     allow_loop_collectives: tuple[str, ...] = ("psum",)
+
+
+def is_jit_call(eqn) -> bool:
+    """A nested ``jax.jit`` call, recognized by its parameters (a closed
+    sub-jaxpr plus per-operand donation flags) rather than by primitive
+    name, which JAX has renamed between releases."""
+    return "jaxpr" in eqn.params and "donated_invars" in eqn.params
 
 
 def iter_eqns(jaxpr, *, in_loop: bool = False
@@ -128,13 +137,12 @@ def iter_eqns(jaxpr, *, in_loop: bool = False
 
 
 def _live_outvars(eqn):
-    drop_var = getattr(jax.core, "DropVar", ())
-    return [v for v in eqn.outvars if not isinstance(v, drop_var)]
+    return [v for v in eqn.outvars if not isinstance(v, jax.core.DropVar)]
 
 
 def count_bxb_intermediates(fn, *args, B: int) -> int:
     """Number of (B, B)-shaped values produced outside Pallas kernels in
-    ``fn``'s jaxpr (descending through pjit/custom_vjp calls; a value coming
+    ``fn``'s jaxpr (descending through jit/custom_vjp calls; a value coming
     straight out of a ``pallas_call`` does not count — the kernel produced
     it tile by tile)."""
     closed = jax.make_jaxpr(fn)(*args)
@@ -217,7 +225,7 @@ def audit_entry(entry: EntryPoint, closed: Any | None = None
             continue
         if in_loop and prim in CALLBACK_PRIMITIVES:
             callback_hits[prim] = callback_hits.get(prim, 0) + 1
-        if donate_name is not None and prim == "pjit" \
+        if donate_name is not None and is_jit_call(eqn) \
                 and eqn.params.get("name") == donate_name:
             donated = eqn.params.get("donated_invars", ())
             donated_ok = (len(donated) >= donate_n
@@ -268,12 +276,12 @@ def audit_entry(entry: EntryPoint, closed: Any | None = None
         if donated_ok is None:
             findings.append(Finding(
                 "jaxpr", "J005", entry.name,
-                f"could not find pjit eqn named {donate_name!r} to verify "
+                f"could not find jit call named {donate_name!r} to verify "
                 "carry donation", detail=f"{donate_name}:missing"))
         elif not donated_ok:
             findings.append(Finding(
                 "jaxpr", "J005", entry.name,
-                f"pjit {donate_name!r} does not donate all "
+                f"jit {donate_name!r} does not donate all "
                 f"{donate_n} carry leaves", detail=donate_name))
 
     # -- J006: captured constants ---------------------------------------

@@ -16,7 +16,8 @@ carries, and flags:
     an outer key inside a scan body counts once per iteration, so a
     captured key drawn in a loop of length n counts n times.
   * ``R002`` — a key consumed inside a scan body *and* returned in the
-    carry unchanged: every iteration draws from the same key.  The fix
+    carry unchanged (which JAX hoists into a loop-invariant scan const):
+    every iteration draws from the same key.  The fix
     is ``fold_in``/``split`` inside the body (the carried token must
     differ from the one consumed).
   * ``R003`` — entropy discarded: a ``random_split`` none of whose
@@ -106,7 +107,7 @@ class _State:
 
 
 def _is_dropvar(v) -> bool:
-    return isinstance(v, getattr(jax.core, "DropVar", ()))
+    return isinstance(v, jax.core.DropVar)
 
 
 def _liveness(jaxpr, live_outvars: set) -> list[bool]:
@@ -280,10 +281,22 @@ def _walk_scan(eqn, env: dict, state: _State, eqn_live: bool) -> None:
         if n_consts <= pos < n_consts + n_carry:
             carry_in.append(tok)
 
+    const_in = [sub_env.get(id(inner)) for inner in sub.invars[:n_consts]]
     consumed_before = {id(t): t.consumed for t in state.tokens}
     state.scan_lengths.append(length)
     _walk(sub, sub_env, state, jaxpr_live=eqn_live)
     state.scan_lengths.pop()
+
+    # R002, hoisted form: JAX turns a carry that the body returns unchanged
+    # into a scan const, so the same bug also arrives as a const key that
+    # the body consumes on every iteration.
+    for pos, tok in enumerate(const_in):
+        if tok is not None and \
+                tok.consumed > consumed_before.get(id(tok), 0):
+            state.findings.append(_finding(
+                "R002", f"loop-invariant key {tok.origin} is drawn from "
+                "inside the scan body — every iteration replays the same "
+                "stream", detail=f"const{pos}:{tok.origin}"))
 
     # R002: a carry key consumed in the body and returned unchanged.
     for pos in range(n_carry):

@@ -38,7 +38,7 @@ from typing import Any
 import jax
 
 from repro.analysis.findings import Finding
-from repro.analysis.jaxpr_audit import EntryPoint, iter_eqns
+from repro.analysis.jaxpr_audit import EntryPoint, is_jit_call, iter_eqns
 
 __all__ = ["audit_entry_sharding", "COLLECTIVE_PRIMITIVES"]
 
@@ -101,7 +101,7 @@ def audit_entry_sharding(entry: EntryPoint, closed: Any | None = None
     audited = 0
     for eqn, in_loop in iter_eqns(closed.jaxpr):
         prim = eqn.primitive.name
-        if prim == "pjit":
+        if is_jit_call(eqn):
             _check_donated_shardings(eqn, entry, findings)
         if prim not in COLLECTIVE_PRIMITIVES:
             continue

@@ -65,6 +65,9 @@ class Block:
     #: (``race_audit``) verifies: any two grid steps mapping to the same
     #: block coordinates must differ only on these axes.
     accum_axes: tuple[int, ...] = ()
+    #: "vmem", or "smem" for a whole-array scalar buffer (the forward
+    #: kernels' parameters and loss accumulator), which takes no VMEM.
+    memory: str = "vmem"
 
     @property
     def nbytes(self) -> int:
@@ -85,10 +88,11 @@ class Launch:
 
     def footprint_bytes(self) -> int:
         """Per-grid-step VMEM working set: 2x in/out (double-buffered
-        pipeline) + 1x scratch."""
+        pipeline) + 1x scratch; SMEM blocks do not count."""
         total = 0
         for b in self.blocks:
-            total += b.nbytes * (1 if b.kind == "scratch" else 2)
+            if b.memory == "vmem":
+                total += b.nbytes * (1 if b.kind == "scratch" else 2)
         return total
 
 
@@ -131,9 +135,9 @@ def _graph_reg_launches(tiles: TileSpec, *, rows: int, classes: int
         Block("W", (bi, bj), "in", index_map=lambda i, j, c: (i, j),
               array_shape=(Bi, Bj)),
         Block("scalars", (1, 4), "in", index_map=lambda i, j, c: (0, 0),
-              array_shape=(1, 4)),
+              array_shape=(1, 4), memory="smem"),
         Block("out", (1, 1), "out", index_map=lambda i, j, c: (0, 0),
-              array_shape=(1, 1), accum_axes=(0, 1, 2)),
+              array_shape=(1, 1), memory="smem", accum_axes=(0, 1, 2)),
         Block("acc", (bi, bj), "scratch"),
         Block("deg", (bi, 1), "scratch"),
         Block("ent", (bi, 1), "scratch"),
@@ -212,9 +216,9 @@ def _blocksparse_launches(tiles: TileSpec, *, rows: int, classes: int
         Block("W", (bt, bt), "in",
               index_map=lambda t, c: (tid(t), tid(t)), array_shape=(P, P)),
         Block("scalars", (1, 4), "in", index_map=lambda t, c: (0, 0),
-              array_shape=(1, 4)),
+              array_shape=(1, 4), memory="smem"),
         Block("out", (1, 1), "out", index_map=lambda t, c: (0, 0),
-              array_shape=(1, 1), accum_axes=(0, 1)),
+              array_shape=(1, 1), memory="smem", accum_axes=(0, 1)),
         Block("acc", (bt, bt), "scratch"),
         Block("deg", (bt, 1), "scratch"),
         Block("ent", (bt, 1), "scratch"),

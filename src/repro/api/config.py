@@ -255,8 +255,8 @@ class TrainConfig:
 
     ``execution="sequential"`` runs the vmapped k-worker step on the default
     device; ``"parallel"`` additionally shards the leading worker axis over a
-    ``("data",)`` mesh of the available devices — the launcher's pjit
-    pattern, which *is* the paper's synchronous k-worker SGD.  (Back-compat
+    ``("data",)`` mesh of the available devices, which *is* the paper's
+    synchronous k-worker SGD.  (Back-compat
     shorthand: ``"parallel"`` selects the engine's ``"sync_mesh"`` strategy
     unless ``ExecutionConfig.strategy`` overrides it.)
     """

@@ -153,7 +153,7 @@ PAIRWISE.register("auto", "repro.kernels.ops:graph_regularizer_auto")
 #:   * ``"sequential"`` — single-device execution;
 #:   * ``"sync_mesh"``  — params replicated over a ``("data",)`` mesh, each
 #:     chunk's worker axis sharded over it (the paper's synchronous k-worker
-#:     SGD, pjit inserting the gradient all-reduce);
+#:     SGD, the gradient all-reduced across it);
 #:   * ``"async_ps"``   — the §4 stale-gradient parameter-server simulation
 #:     (snapshots + round-robin schedule inside the scan body).
 STRATEGY = Registry("strategy")

@@ -12,6 +12,7 @@ import argparse
 import json
 
 from repro.api import Experiment, ExperimentConfig
+from repro.compile_cache import enable_compilation_cache
 
 
 def main() -> None:
@@ -37,6 +38,7 @@ def main() -> None:
         print(json.dumps(cfg.to_dict(), indent=2))
         return
 
+    enable_compilation_cache()
     result = Experiment(cfg).run()
     for row in result.history:
         acc = f" eval/acc={row['eval/acc']:.4f}" if "eval/acc" in row else ""

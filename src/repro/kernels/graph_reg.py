@@ -54,6 +54,18 @@ DEFAULT_BI = 128
 DEFAULT_BJ = 128
 DEFAULT_BC = 512
 
+#: Every MXU contraction asks for full f32 precision.  Mosaic's default for
+#: f32 operands is one bf16 pass (a v5e kernel compiled with the default is
+#: the size of its bf16-input twin; with HIGHEST it is ~5x larger), which
+#: would leave the compiled regularizer ~1e-3 off its f32 reference.
+#: Interpret mode computes in f32 either way, so no CPU test can see it.
+_F32 = jax.lax.Precision.HIGHEST
+
+#: Whole-array scalar memory: the forward kernels' (1, 4) parameter input
+#: and their (1, 1) accumulated output.  Mosaic cannot store a scalar into a
+#: VMEM block, so the loss accumulator lives in SMEM.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
 
 def _pad2(a: jax.Array, pr: int, pc: int) -> jax.Array:
     return jnp.pad(a, ((0, pr), (0, pc))) if (pr or pc) else a
@@ -82,7 +94,7 @@ def _graph_reg_kernel(p_ref, logp_ref, w_ref, out_ref, acc_ref, *,
     acc_ref[...] += jax.lax.dot_general(
         p_ref[...], logp_ref[...],
         (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_F32, preferred_element_type=jnp.float32)
 
     i, j = pl.program_id(0), pl.program_id(1)
 
@@ -119,7 +131,7 @@ def _fused_reg_kernel(p_ref, logpj_ref, logpi_ref, w_ref, s_ref, out_ref,
     acc_ref[...] += jax.lax.dot_general(
         p_ref[...], logpj_ref[...],
         (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_F32, preferred_element_type=jnp.float32)
 
     @pl.when(j == 0)
     def _entropy_chunk():
@@ -163,9 +175,9 @@ def _fused_reg_forward(
             pl.BlockSpec((bj, bc), lambda i, j, c: (j, c)),
             pl.BlockSpec((bi, bc), lambda i, j, c: (i, c)),
             pl.BlockSpec((bi, bj), lambda i, j, c: (i, j)),
-            pl.BlockSpec((1, 4), lambda i, j, c: (0, 0)),
+            _SMEM,
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j, c: (0, 0)),
+        out_specs=_SMEM,
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((bi, bj), jnp.float32),   # S tile accumulator
@@ -246,7 +258,7 @@ def graph_reg_pairwise_pallas(
             pl.BlockSpec((bj, bc), lambda i, j, c: (j, c)),
             pl.BlockSpec((bi, bj), lambda i, j, c: (i, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j, c: (0, 0)),
+        out_specs=_SMEM,
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         # VMEM scratch accumulator for the S tile.
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
@@ -275,12 +287,12 @@ def _reg_bwd_dlogp_kernel(w_ref, wt_ref, pj_ref, logpj_ref, pi_ref,
 
     # A += W[i-blk, j-blk] @ logP[j-blk, c-blk]        (the W·logP term)
     a_ref[...] += jnp.dot(w_ref[...], logpj_ref[...],
-                          preferred_element_type=jnp.float32)
+                          precision=_F32, preferred_element_type=jnp.float32)
     # B += W[j-blk, i-blk]ᵀ @ P[j-blk, c-blk]          (the Wᵀ·P term)
     b_ref[...] += jax.lax.dot_general(
         wt_ref[...], pj_ref[...],
         (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_F32, preferred_element_type=jnp.float32)
 
     @pl.when(c == 0)
     def _deg_chunk():
@@ -312,7 +324,7 @@ def _reg_bwd_dw_kernel(pi_ref, logpj_ref, logpi_ref, s_ref, out_ref,
     acc_ref[...] += jax.lax.dot_general(
         pi_ref[...], logpj_ref[...],
         (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_F32, preferred_element_type=jnp.float32)
 
     @pl.when(j == 0)
     def _entropy_chunk():
@@ -494,7 +506,7 @@ def _bsp_fwd_kernel(rows_ref, cols_ref, valid_ref, p_ref, logpj_ref,
         acc_ref[...] += jax.lax.dot_general(
             p_ref[...], logpj_ref[...],
             (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=_F32, preferred_element_type=jnp.float32)
 
     @pl.when(first)
     def _entropy_chunk():
@@ -542,10 +554,9 @@ def _bsp_forward(
                          (rows[t], c)),
             pl.BlockSpec((bt, bt), lambda t, c, rows, cols, valid:
                          (rows[t], cols[t])),
-            pl.BlockSpec((1, 4), lambda t, c, rows, cols, valid: (0, 0)),
+            _SMEM,
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda t, c, rows, cols, valid:
-                               (0, 0)),
+        out_specs=_SMEM,
         scratch_shapes=[
             pltpu.VMEM((bt, bt), jnp.float32),   # S tile accumulator
             pltpu.VMEM((bt, 1), jnp.float32),    # row degrees
@@ -605,7 +616,7 @@ def _bsp_bterm_kernel(crows_ref, ccols_ref, cvalid_ref, w_ref, pj_ref,
         b_ref[...] += jax.lax.dot_general(
             w_ref[...], pj_ref[...],
             (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=_F32, preferred_element_type=jnp.float32)
 
     @pl.when(last)
     def _write():
@@ -631,7 +642,7 @@ def _bsp_dlogp_kernel(rows_ref, cols_ref, valid_ref, w_ref, logpj_ref,
     @pl.when(live)
     def _acc():
         # A += W[i-blk, j-blk] @ logP[j-blk, c-blk]
-        a_ref[...] += jnp.dot(w_ref[...], logpj_ref[...],
+        a_ref[...] += jnp.dot(w_ref[...], logpj_ref[...], precision=_F32,
                               preferred_element_type=jnp.float32)
         deg_ref[...] += jnp.sum(w_ref[...], axis=1, keepdims=True)
 
@@ -666,7 +677,7 @@ def _bsp_dw_kernel(occ_ref, pi_ref, logpj_ref, logpi_ref, s_ref, out_ref,
         acc_ref[...] += jax.lax.dot_general(
             pi_ref[...], logpj_ref[...],
             (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=_F32, preferred_element_type=jnp.float32)
 
     @pl.when(j == 0)
     def _entropy_chunk():

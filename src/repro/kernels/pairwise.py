@@ -38,6 +38,9 @@ DEFAULT_BI = 128
 DEFAULT_BJ = 128
 DEFAULT_BD = 256
 
+#: Full f32 MXU contractions: Mosaic's default for f32 operands is one bf16
+#: pass, too coarse for exact neighbour sets (see ``graph_reg._F32``).
+_F32 = jax.lax.Precision.HIGHEST
 _BIG = 3.4e38                       # "+inf" that survives arithmetic
 _BIG_POS = 2 ** 30
 
@@ -52,7 +55,7 @@ def _pairwise_kernel(x_ref, y_ref, nx_ref, ny_ref, sig_ref, out_ref, acc_ref,
 
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], y_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_F32, preferred_element_type=jnp.float32)
 
     @pl.when(di == n_d_blocks - 1)
     def _finish():
@@ -118,7 +121,7 @@ def _topk_kernel(x_ref, y_ref, nx_ref, ny_ref, out_d2_ref, out_idx_ref,
 
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], y_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_F32, preferred_element_type=jnp.float32)
 
     @pl.when(d == n_d - 1)
     def _merge_chunk():

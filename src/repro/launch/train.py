@@ -31,6 +31,8 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.smoke:
+        from repro.compile_cache import enable_compilation_cache
+        enable_compilation_cache()
         _run_smoke(args)
         return
     # Dry-run path: delegate (sets XLA_FLAGS before jax import).

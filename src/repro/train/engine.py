@@ -16,8 +16,8 @@ name in the ``repro.api.registry.STRATEGY`` registry:
     default device);
   * ``"sync_mesh"``  — the paper's k-worker synchronous SGD: parameters
     replicated over a ``("data",)`` mesh, each chunk's worker axis sharded
-    over it, pjit inserting the gradient all-reduce the parameter server
-    performed;
+    over it, each device computing its workers' losses under ``shard_map``
+    and the gradient all-reduce standing in for the parameter server;
   * ``"async_ps"``   — the §4 stale-gradient parameter-server simulation:
     each of k workers holds a snapshot up to ``max_staleness`` server steps
     old, gradients are taken at the snapshot and applied to the live
@@ -131,11 +131,14 @@ def data_mesh(n_workers: int):
     """``(MESH_AXIS,)`` mesh whose size is the largest divisor of
     ``n_workers`` realizable on the available devices (1 on a
     single-device host — the sharded arrays then simply live on that
-    device)."""
+    device).  The axis is ``AxisType.Auto``: the strategy's shardings are
+    placement hints for the partitioner, so traced values keep unsharded
+    types and ``vmap``/``value_and_grad`` see plain arrays."""
     n_dev = len(jax.devices())
     size = max(d for d in range(1, min(n_workers, n_dev) + 1)
                if n_workers % d == 0)
-    return jax.make_mesh((size,), (MESH_AXIS,))
+    return jax.make_mesh((size,), (MESH_AXIS,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 # ------------------------------------------------------------------ prefetch
@@ -252,9 +255,11 @@ class SequentialStrategy:
 
 
 class SyncMeshStrategy(SequentialStrategy):
-    """The current pjit data-parallel path: params replicated over a
-    ``("data",)`` mesh, each chunk's leading worker axis (axis 1 — axis 0 is
-    the scan axis) sharded over it."""
+    """Data-parallel k workers: params replicated over a ``("data",)``
+    mesh, each chunk's leading worker axis (axis 1 — axis 0 is the scan
+    axis) sharded over it.  The step maps its workers under ``shard_map``
+    (``dnn_ssl_loss(mesh=...)``), since Pallas kernels cannot be
+    partitioned automatically."""
 
     def __init__(self, engine: "Engine"):
         super().__init__(engine)
@@ -270,8 +275,10 @@ class SyncMeshStrategy(SequentialStrategy):
         return jax.device_put(state, self._replicated)
 
     def place_batch(self, chunk: dict) -> dict:
-        return jax.tree.map(
-            lambda a: jax.device_put(jnp.asarray(a), self._sharded), chunk)
+        # Straight from host memory: each device receives only its shard
+        # (staging through jnp.asarray would land the whole chunk on the
+        # default device first).
+        return jax.device_put(chunk, self._sharded)
 
     def place_carry(self, carry):
         return jax.device_put(carry, self._replicated)

@@ -2,9 +2,11 @@
 
 ``dnn_ssl_step``   — the paper's objective (Eq. 3) on the 4×2000 DNN, over a
                      (k, P, ·) stack of concatenated meta-batches.  Under the
-                     launcher the leading axis is sharded over ("pod","data"),
-                     which *is* the paper's k-worker synchronous SGD: pjit
-                     inserts the gradient all-reduce the parameter server did.
+                     ``sync_mesh`` strategy the leading axis is sharded over
+                     the data mesh, which *is* the paper's k-worker
+                     synchronous SGD: each device maps its workers under
+                     ``shard_map`` and the gradient all-reduce does what the
+                     parameter server did.
 ``lm_train_step``  — next-token loss for any assigned architecture, with the
                      paper's graph regularizer attached at the sequence level
                      (pooled output distribution + dense affinity block W).
@@ -33,7 +35,8 @@ _TILE_KEYS = ("tile_rows", "tile_cols", "tile_valid",
 
 
 def dnn_ssl_loss(params, batch: dict, cfg: DNNConfig, hyper: SSLHyper,
-                 *, dropout_rng=None, dropout: float = 0.0, pairwise=None):
+                 *, dropout_rng=None, dropout: float = 0.0, pairwise=None,
+                 mesh=None):
     """Mean Eq.-3 loss over the k stacked concatenated batches.
 
     ``pairwise`` names a PAIRWISE registry entry ("ref" | "pallas" |
@@ -42,12 +45,18 @@ def dnn_ssl_loss(params, batch: dict, cfg: DNNConfig, hyper: SSLHyper,
     When the pipeline attached a block layout (the ``tile_*`` batch keys,
     from ``BatchConfig.layout_bt``) it rides through the vmap and into
     layout-aware kernels, which skip W's structurally-zero tiles.
+
+    ``mesh`` (the ``sync_mesh`` strategy's data mesh) maps the workers
+    under ``shard_map``: each device runs the per-worker loss of its own
+    shard of k, because the compiler cannot partition a Pallas kernel by
+    itself.  The mean over workers, and hence the gradient all-reduce
+    (the transpose of the replicated ``params`` input), stays outside.
     """
     tile_args = ([batch[k] for k in _TILE_KEYS]
                  if all(batch.get(k) is not None for k in _TILE_KEYS)
                  else [])
 
-    def per_worker(x, y, mask, W, valid, *tiles):
+    def per_worker(params, dropout_rng, x, y, mask, W, valid, *tiles):
         logits = dnn_forward(params, x, dropout_rng=dropout_rng,
                              dropout=dropout)
         # Padding rows: zero affinity + zero label mask + masked entropy term.
@@ -58,14 +67,23 @@ def dnn_ssl_loss(params, batch: dict, cfg: DNNConfig, hyper: SSLHyper,
             layout=tuple(tiles) or None, reduction="mean")
         return loss, metrics
 
-    losses, metrics = jax.vmap(per_worker)(
-        batch["x"], batch["y"], batch["label_mask"], batch["W"],
-        batch["valid"].astype(jnp.float32), *tile_args)
+    per_k = (batch["x"], batch["y"], batch["label_mask"], batch["W"],
+             batch["valid"].astype(jnp.float32), *tile_args)
+    workers = jax.vmap(per_worker, in_axes=(None, None) + (0,) * len(per_k))
+    if mesh is not None:
+        P = jax.sharding.PartitionSpec
+        # check_vma=False: Pallas outputs carry no varying-axes type.
+        workers = jax.shard_map(
+            workers, mesh=mesh,
+            in_specs=(P(), P()) + (P(mesh.axis_names),) * len(per_k),
+            out_specs=P(mesh.axis_names), check_vma=False)
+    losses, metrics = workers(params, dropout_rng, *per_k)
     return jnp.mean(losses), jax.tree.map(jnp.mean, metrics)
 
 
 def dnn_ssl_grads(params, batch: dict, *, cfg: DNNConfig, hyper: SSLHyper,
-                  dropout_rng=None, dropout: float = 0.0, pairwise=None):
+                  dropout_rng=None, dropout: float = 0.0, pairwise=None,
+                  mesh=None):
     """``(grads, metrics)`` of the Eq.-3 loss at ``params``.
 
     The shared gradient core: ``dnn_ssl_step`` applies it synchronously;
@@ -76,17 +94,18 @@ def dnn_ssl_grads(params, batch: dict, *, cfg: DNNConfig, hyper: SSLHyper,
     (loss, metrics), grads = jax.value_and_grad(
         dnn_ssl_loss, has_aux=True)(params, batch, cfg, hyper,
                                     dropout_rng=dropout_rng, dropout=dropout,
-                                    pairwise=pairwise)
+                                    pairwise=pairwise, mesh=mesh)
     metrics["loss/total"] = loss
     return grads, metrics
 
 
 def dnn_ssl_step(params, opt_state, batch: dict, *, cfg: DNNConfig,
                  hyper: SSLHyper, opt: Optimizer, lr: Array,
-                 dropout_rng=None, dropout: float = 0.0, pairwise=None):
+                 dropout_rng=None, dropout: float = 0.0, pairwise=None,
+                 mesh=None):
     grads, metrics = dnn_ssl_grads(params, batch, cfg=cfg, hyper=hyper,
                                    dropout_rng=dropout_rng, dropout=dropout,
-                                   pairwise=pairwise)
+                                   pairwise=pairwise, mesh=mesh)
     new_params, new_state = opt.update(grads, opt_state, params, lr)
     return new_params, new_state, metrics
 

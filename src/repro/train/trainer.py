@@ -88,8 +88,9 @@ def train_dnn_ssl(
     ``strategy`` names a STRATEGY registry entry; when omitted it is
     inferred: ``"sync_mesh"`` if ``mesh`` (a ``("data",)`` mesh) is given —
     parameters replicated, each batch's leading worker axis sharded over it,
-    the paper's k-worker synchronous SGD with pjit inserting the gradient
-    all-reduce the parameter server performed — else ``"sequential"``.
+    the paper's k-worker synchronous SGD, each device computing its
+    workers' losses under ``shard_map`` and all-reducing the gradient as the
+    parameter server did — else ``"sequential"``.
     ``"async_ps"`` runs the §4 stale-gradient regime (``max_staleness``
     server steps of lag, dropout off — the async server pushes no rng).
 
@@ -135,6 +136,7 @@ def train_dnn_ssl(
     # Resolve the pairwise kernel once; everything below passes the callable.
     from repro.api.registry import resolve_pairwise
     pairwise = resolve_pairwise(pairwise)
+    worker_mesh = mesh if strategy == "sync_mesh" else None
 
     def step_fn(s: TrainState, batch: dict, lr):
         # Same split order as the historical Python loop: carry keeps the
@@ -142,7 +144,8 @@ def train_dnn_ssl(
         rng, sub = jax.random.split(s.rng)
         p, o, metrics = dnn_ssl_step(
             s.params, s.opt_state, batch, cfg=cfg, hyper=hyper, opt=opt,
-            lr=lr, dropout_rng=sub, dropout=dropout, pairwise=pairwise)
+            lr=lr, dropout_rng=sub, dropout=dropout, pairwise=pairwise,
+            mesh=worker_mesh)
         return TrainState(params=p, opt_state=o, rng=rng,
                           step=s.step + 1), metrics
 

@@ -96,10 +96,8 @@ class TestJaxprAudit:
         assert good_f == []
 
     def test_f64_leak_flagged(self):
-        from jax.experimental import enable_x64
-
         x = jnp.zeros((8, 8), jnp.float32)
-        with enable_x64():
+        with jax.enable_x64(True):
             findings, _ = audit_entry(EntryPoint(
                 "leak", lambda: ((lambda x: x.astype(jnp.float64) * 2.0),
                                  (x,))))

@@ -49,7 +49,8 @@ def test_permuted_graph_preserves_weights(small_graph_setup):
     perm = partition_permutation(plan.mini_block_labels)
     gp = graph.permuted(perm)
     assert gp.W.nnz == graph.W.nnz
-    np.testing.assert_allclose(gp.W.sum(), graph.W.sum(), rtol=1e-9)
+    np.testing.assert_allclose(gp.W.sum(dtype=np.float64),
+                               graph.W.sum(dtype=np.float64), rtol=1e-9)
     # spot check: entry (a, b) in permuted == (perm[a], perm[b]) in original
     a, b = 3, 17
     np.testing.assert_allclose(gp.W[a, b], graph.W[perm[a], perm[b]])

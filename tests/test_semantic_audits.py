@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -382,9 +381,9 @@ class TestShardingAudit:
 
     def _psum_fn(self):
         def f(x):
-            return shard_map(lambda a: jax.lax.psum(a, "data"),
-                             mesh=self.mesh, in_specs=P("data"),
-                             out_specs=P(), check_rep=False)(x)
+            return jax.shard_map(lambda a: jax.lax.psum(a, "data"),
+                                 mesh=self.mesh, in_specs=P("data"),
+                                 out_specs=P(), check_vma=False)(x)
         return f
 
     def test_s001_undeclared_axis_flagged(self):
@@ -407,8 +406,9 @@ class TestShardingAudit:
             return out
 
         def f(x):
-            return shard_map(body_fn, mesh=self.mesh, in_specs=P("data"),
-                             out_specs=P(), check_rep=False)(x)
+            return jax.shard_map(body_fn, mesh=self.mesh,
+                                 in_specs=P("data"), out_specs=P(),
+                                 check_vma=False)(x)
         return f
 
     def test_s002_gather_in_loop_flagged(self):

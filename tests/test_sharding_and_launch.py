@@ -125,12 +125,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, json
 from repro.launch.inputs import input_specs
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(data=4, model=2)
 spec = input_specs("qwen1.5-0.5b", "decode_32k", mesh, "fsdp_tp")
 with mesh:
     compiled = jax.jit(spec["fn"], donate_argnums=spec["donate"]).lower(*spec["args"]).compile()
 ca = compiled.cost_analysis()
-ca = ca[0] if isinstance(ca, (list, tuple)) else ca  # list-of-dicts pre-0.5
 print(json.dumps({"ok": True, "flops": ca.get("flops", 0)}))
 """
     env = dict(os.environ)
