@@ -27,6 +27,7 @@ import numpy as np
 from repro.api.config import ExperimentConfig
 from repro.api.registry import (AFFINITY, OPTIMIZER, PARTITIONER, PIPELINE,
                                 resolve_pairwise)
+from repro.tracing import span
 
 __all__ = ["Experiment", "ExperimentResult"]
 
@@ -81,7 +82,8 @@ class Experiment:
             return self
         cfg = self.config
         if self.corpus is None:
-            self.corpus, self.eval_data = self._make_data()
+            with span("build.corpus"):
+                self.corpus, self.eval_data = self._make_data()
         if self.graph is None:
             builder = AFFINITY.get(cfg.graph.builder)
             # Only forward the construction backend to builders that take
@@ -103,18 +105,20 @@ class Experiment:
                     f"accept a backend= argument")
             else:
                 kw = {}
-            self.graph = builder(self.corpus.X, k=cfg.graph.k,
-                                 sigma=cfg.graph.sigma, **kw)
+            with span("build.graph"):
+                self.graph = builder(self.corpus.X, k=cfg.graph.k,
+                                     sigma=cfg.graph.sigma, **kw)
         needs_plan = cfg.batch.pipeline != "random_batch"
         if self.plan is None and needs_plan:
             from repro.core.metabatch import plan_meta_batches
-            self.plan = plan_meta_batches(
-                self.graph, batch_size=cfg.batch.batch_size,
-                n_classes=self.corpus.n_classes, seed=cfg.data.seed,
-                tol=cfg.partition.tol,
-                shuffle_blocks=cfg.batch.shuffle_blocks,
-                partitioner=PARTITIONER.get(cfg.partition.method),
-                coarsen_to=cfg.partition.coarsen_to)
+            with span("build.plan"):
+                self.plan = plan_meta_batches(
+                    self.graph, batch_size=cfg.batch.batch_size,
+                    n_classes=self.corpus.n_classes, seed=cfg.data.seed,
+                    tol=cfg.partition.tol,
+                    shuffle_blocks=cfg.batch.shuffle_blocks,
+                    partitioner=PARTITIONER.get(cfg.partition.method),
+                    coarsen_to=cfg.partition.coarsen_to)
         factory = PIPELINE.get(cfg.batch.pipeline)
         # The async parameter-server regime consumes 1-worker batches
         # round-robin (k lives in the engine strategy, not the pipeline).
@@ -123,26 +127,27 @@ class Experiment:
         # Extra keys are swallowed by factories that don't need them (the
         # uniform ``**_`` contract): the stream pipeline consumes the
         # re-partitioning config and the partition settings it re-runs with.
-        self.pipeline = factory(
-            self.corpus, self.graph, self.plan,
-            batch_size=cfg.batch.batch_size,
-            n_workers=pipeline_workers,
-            with_neighbor=cfg.batch.with_neighbor,
-            pad_factor=cfg.batch.pad_factor,
-            pad_headroom=cfg.batch.pad_headroom,
-            seed=cfg.data.seed,
-            repartition=cfg.repartition,
-            partitioner=PARTITIONER.get(cfg.partition.method),
-            tol=cfg.partition.tol,
-            coarsen_to=cfg.partition.coarsen_to,
-            shuffle_blocks=cfg.batch.shuffle_blocks,
-            hierarchy_cache=self._hierarchy_cache(),
-            supervisor=self._replan_supervisor(),
-            fault_injector=self.injector,
-            record_indices=cfg.online.active,
-            layout_bt=cfg.batch.layout_bt)
-        if cfg.online.active:
-            self.online = self._make_online_manager()
+        with span("build.pipeline"):
+            self.pipeline = factory(
+                self.corpus, self.graph, self.plan,
+                batch_size=cfg.batch.batch_size,
+                n_workers=pipeline_workers,
+                with_neighbor=cfg.batch.with_neighbor,
+                pad_factor=cfg.batch.pad_factor,
+                pad_headroom=cfg.batch.pad_headroom,
+                seed=cfg.data.seed,
+                repartition=cfg.repartition,
+                partitioner=PARTITIONER.get(cfg.partition.method),
+                tol=cfg.partition.tol,
+                coarsen_to=cfg.partition.coarsen_to,
+                shuffle_blocks=cfg.batch.shuffle_blocks,
+                hierarchy_cache=self._hierarchy_cache(),
+                supervisor=self._replan_supervisor(),
+                fault_injector=self.injector,
+                record_indices=cfg.online.active,
+                layout_bt=cfg.batch.layout_bt)
+            if cfg.online.active:
+                self.online = self._make_online_manager()
         self._built = True
         return self
 
@@ -286,7 +291,7 @@ class Experiment:
 
             capture_epochs = self.online.capture_epoch
             on_epoch_end = self.online.on_epoch_end
-        t0 = time.time()
+        t0 = time.perf_counter()
         res = train_dnn_ssl(
             self.pipeline,
             cfg=model_cfg,
@@ -312,7 +317,7 @@ class Experiment:
             capture_fn=capture_fn,
             capture_epochs=capture_epochs,
             on_epoch_end=on_epoch_end)
-        seconds = time.time() - t0
+        seconds = time.perf_counter() - t0
         final = res.history[-1] if res.history else {}
         return ExperimentResult(config=cfg, history=res.history,
                                 final=final, seconds=seconds,

@@ -31,6 +31,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.tracing import scope
+
 __all__ = [
     "SSLHyper",
     "entropy",
@@ -175,8 +177,9 @@ def ssl_objective(
     picked = jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32), axis=1)[:, 0]
     sup = -jnp.sum(picked * label_mask)
     n_labeled = jnp.maximum(jnp.sum(label_mask), 1.0)
-    greg = graph_regularizer(logp, W, hyper.gamma, hyper.kappa,
-                             pairwise=pairwise, layout=layout)
+    with scope("graph_reg"):
+        greg = graph_regularizer(logp, W, hyper.gamma, hyper.kappa,
+                                 pairwise=pairwise, layout=layout)
     l2 = hyper.weight_decay * l2_penalty(params) if params is not None else jnp.float32(0)
     if reduction == "mean":
         b = logits.shape[0]

@@ -38,6 +38,7 @@ from repro.core.partition import HierarchyCache
 from repro.core.partition import partition_graph as partition_graph_default
 from repro.data.synthetic_timit import SyntheticCorpus
 from repro.introspect import accepts_kwarg
+from repro.tracing import span
 
 __all__ = ["SSLBatch", "MetaBatchPipeline", "MetaBatchStream",
            "random_batch_pipeline", "make_meta_batch_pipeline",
@@ -85,19 +86,22 @@ def _assemble(corpus: SyntheticCorpus, graph: AffinityGraph,
     on the pipeline/prefetch producer thread — zero per-step layout work
     on the training path.
     """
-    W = _pad_to(_pad_to(graph.dense_block(idx), P, 0), P, 1)
-    base = (_pad_to(corpus.X[idx], P),
-            _pad_to(corpus.y[idx], P),
-            _pad_to(corpus.label_mask[idx].astype(np.float32), P),
-            W,
-            _pad_to(np.ones(len(idx), bool), P))
-    if layout_bt is None:
-        return base
-    return base + block_layout(W, layout_bt, list_len=layout_len).arrays()
+    with span("pipeline.block"):
+        with span("pipeline.densify"):
+            W = _pad_to(_pad_to(graph.dense_block(idx), P, 0), P, 1)
+        base = (_pad_to(corpus.X[idx], P),
+                _pad_to(corpus.y[idx], P),
+                _pad_to(corpus.label_mask[idx].astype(np.float32), P),
+                W,
+                _pad_to(np.ones(len(idx), bool), P))
+        if layout_bt is None:
+            return base
+        return base + block_layout(W, layout_bt, list_len=layout_len).arrays()
 
 
 def _stack_group(parts) -> SSLBatch:
-    cols = [np.stack(c) for c in zip(*parts)]
+    with span("pipeline.stack"):
+        cols = [np.stack(c) for c in zip(*parts)]
     return SSLBatch(*cols)   # 5 base columns, +7 tile columns with a layout
 
 
@@ -339,10 +343,11 @@ class MetaBatchStream:
     def _call_synthesize(self, epoch: int) -> MetaBatchPlan:
         """One supervised synthesis: with a supervisor, transient failures
         are retried with backoff before the degrade path ever fires."""
-        if self.supervisor is not None:
-            return self.supervisor.call(self._synthesize, epoch,
-                                        key=f"replan@{epoch}")
-        return self._synthesize(epoch)
+        with span("replan.synthesize"):
+            if self.supervisor is not None:
+                return self.supervisor.call(self._synthesize, epoch,
+                                            key=f"replan@{epoch}")
+            return self._synthesize(epoch)
 
     def _note_failure(self, target: int, err: BaseException, *,
                       stacklevel: int) -> None:
@@ -424,11 +429,16 @@ class MetaBatchStream:
                 return
             self._pending = None
         _, t, box = pending
-        t.join()   # happens-before: orders the builder's writes to box
-        if "error" in box:
-            self._note_failure(epoch, box["error"], stacklevel=3)
-            return
-        if not self._swap_in(box["plan"], epoch):
+        with span("replan.join") as sp:
+            t.join()   # happens-before: orders the builder's writes to box
+            if "error" in box:
+                sp.set_metadata(outcome="failed")
+                self._note_failure(epoch, box["error"], stacklevel=3)
+                return
+            if self._swap_in(box["plan"], epoch):
+                sp.set_metadata(outcome="swapped")
+                return
+            sp.set_metadata(outcome="kept")
             with self._lock:
                 self._failed.add(epoch)
 
@@ -488,47 +498,50 @@ class MetaBatchStream:
         internal counter advances by one per call.  ``n_epochs`` bounds the
         run so no background plan is computed past the final epoch.
         """
-        with self._lock:
-            e = self._epoch_counter if epoch is None else int(epoch)
-            self._epoch_counter = e + 1
-        if self.every > 0:
-            self._collect(e)
-            target = (e // self.every) * self.every
+        # The prologue up to the first block; closed before the first
+        # yield, so the consumer's time is never booked to it.
+        with span("pipeline.epoch_begin"):
             with self._lock:
-                need_sync = (target > 0 and self._plan_epoch != target
-                             and target not in self._failed
-                             and not self._replan_disabled)
+                e = self._epoch_counter if epoch is None else int(epoch)
+                self._epoch_counter = e + 1
+            if self.every > 0:
+                self._collect(e)
+                target = (e // self.every) * self.every
+                with self._lock:
+                    need_sync = (target > 0 and self._plan_epoch != target
+                                 and target not in self._failed
+                                 and not self._replan_disabled)
+                    if need_sync:
+                        self._pending = None
                 if need_sync:
-                    self._pending = None
-            if need_sync:
-                # Jumped over the swap epoch (resume, or out-of-order
-                # call): synthesize the plan epoch ``e`` should be using,
-                # synchronously.
-                try:
-                    plan = self._call_synthesize(target)
-                except Exception as err:  # noqa: BLE001 — degrade like bg
-                    self._note_failure(target, err, stacklevel=2)
-                else:
-                    if not self._swap_in(plan, target):
-                        with self._lock:
-                            self._failed.add(target)
-            nxt = self._next_target(e)
+                    # Jumped over the swap epoch (resume, or out-of-order
+                    # call): synthesize the plan epoch ``e`` should be using,
+                    # synchronously.
+                    try:
+                        plan = self._call_synthesize(target)
+                    except Exception as err:  # noqa: BLE001 — degrade like bg
+                        self._note_failure(target, err, stacklevel=2)
+                    else:
+                        if not self._swap_in(plan, target):
+                            with self._lock:
+                                self._failed.add(target)
+                nxt = self._next_target(e)
+                with self._lock:
+                    may_launch = (self._pending is None
+                                  and not self._replan_disabled
+                                  and (n_epochs is None or nxt < n_epochs))
+                # Epochs are consumed one at a time, so only this generator
+                # launches — the lock above is for visibility, not exclusion.
+                if may_launch:
+                    self._launch(nxt)
             with self._lock:
-                may_launch = (self._pending is None
-                              and not self._replan_disabled
-                              and (n_epochs is None or nxt < n_epochs))
-            # Epochs are consumed one at a time, so only this generator
-            # launches — the lock above is for visibility, not exclusion.
-            if may_launch:
-                self._launch(nxt)
-        with self._lock:
-            # One snapshot for the whole epoch: plan, graph and corpus swap
-            # together (replans and online refreshes), never mid-epoch.
-            plan, graph, corpus = self.plan, self.graph, self.corpus
-        sampler = NeighborSampler(
-            plan.batch_edges, seed=epoch_plan_seed(self.seed + 1, e))
-        order_rng = np.random.default_rng([self.seed, 2, e])
-        order = order_rng.permutation(plan.n_meta)
+                # One snapshot for the whole epoch: plan, graph and corpus swap
+                # together (replans and online refreshes), never mid-epoch.
+                plan, graph, corpus = self.plan, self.graph, self.corpus
+            sampler = NeighborSampler(
+                plan.batch_edges, seed=epoch_plan_seed(self.seed + 1, e))
+            order_rng = np.random.default_rng([self.seed, 2, e])
+            order = order_rng.permutation(plan.n_meta)
         recorded: list[list[np.ndarray]] = []
         for group in _epoch_groups(order, self.k):
             parts, idxs = [], []

@@ -184,6 +184,7 @@ def _fused_reg_forward(
             pltpu.VMEM((bi, 1), jnp.float32),    # row degrees
             pltpu.VMEM((bi, 1), jnp.float32),    # row entropies
         ],
+        name="graph_reg_fused_reg_forward",
         interpret=interpret,
     )(p.astype(jnp.float32), logpj.astype(jnp.float32),
       logpi.astype(jnp.float32), Wp.astype(jnp.float32), scalars)
@@ -262,6 +263,7 @@ def graph_reg_pairwise_pallas(
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         # VMEM scratch accumulator for the S tile.
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
+        name="graph_reg_cross",
         interpret=interpret,
     )(p.astype(jnp.float32), logp_p.astype(jnp.float32),
       Wp.astype(jnp.float32))
@@ -372,6 +374,7 @@ def _reg_bwd_dlogp(
             pltpu.VMEM((bi, bc), jnp.float32),   # (Wᵀ·P) tile
             pltpu.VMEM((bi, 1), jnp.float32),    # row degrees
         ],
+        name="graph_reg_bwd_dlogp",
         interpret=interpret,
     )(Wp.astype(jnp.float32), Wp.astype(jnp.float32),
       pj.astype(jnp.float32), logpj.astype(jnp.float32),
@@ -406,6 +409,7 @@ def _reg_bwd_dw(
             pltpu.VMEM((bi, bj), jnp.float32),   # S tile
             pltpu.VMEM((bi, 1), jnp.float32),    # row entropies
         ],
+        name="graph_reg_bwd_dw",
         interpret=interpret,
     )(pi.astype(jnp.float32), logpj.astype(jnp.float32),
       logpi.astype(jnp.float32), scalars)
@@ -567,6 +571,7 @@ def _bsp_forward(
         functools.partial(_bsp_fwd_kernel, n_t=T, n_c=n_c),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        name="graph_reg_bsp_forward",
         interpret=interpret,
     )(rows, cols, valid, p, logpp, logpp, Wp, scalars)
     return out[0, 0]
@@ -726,6 +731,7 @@ def _bsp_bwd(
             scratch_shapes=[pltpu.VMEM((bt, bc), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((P, Cc), jnp.float32),
+        name="graph_reg_bsp_bwd_bterm",
         interpret=interpret,
     )(crows, ccols, cvalid, Wp, p)
     # Pass 2 — row-major sweep folds A = W·logP, degrees and bterm into
@@ -757,6 +763,7 @@ def _bsp_bwd(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((P, Cc), jnp.float32),
+        name="graph_reg_bsp_bwd_dlogp",
         interpret=interpret,
     )(rows, cols, valid, Wp, logpp, p, logpp, bterm, scalars)
     # dW — predicated-dense grid: MXU work only on occupied tiles, but
@@ -779,6 +786,7 @@ def _bsp_bwd(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((P, P), jnp.float32),
+        name="graph_reg_bsp_bwd_dw",
         interpret=interpret,
     )(occ.reshape(-1), p, logpp, logpp, scalars)
     if pad_r:

@@ -96,6 +96,7 @@ def rbf_affinity_pallas(
         out_specs=pl.BlockSpec((bi, bj), lambda i, j, d: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N + pi, M + pj), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
+        name="rbf_affinity",
         interpret=interpret,
     )(xp.astype(jnp.float32), yp.astype(jnp.float32), nx, ny, sig)
     return out[:N, :M]
@@ -194,6 +195,7 @@ def _knn_topk(x, y, *, k, exclude_self, bi, bj, bd, interpret):
             pltpu.VMEM((bi, k), jnp.float32),    # running top-k distances
             pltpu.VMEM((bi, k), jnp.int32),      # running top-k indices
         ],
+        name="knn_topk",
         interpret=interpret,
     )(xp.astype(jnp.float32), yp.astype(jnp.float32), nx, ny)
     return d2[:N], idx[:N]

@@ -51,6 +51,7 @@ import numpy as np
 from repro.introspect import accepts_kwarg
 from repro.resilience.guard import NonFiniteHaltError, all_finite, guard_init
 from repro.resilience.supervisor import Supervisor
+from repro.tracing import scope, span
 from repro.train.checkpoint import (atomic_write_text, load_checkpoint,
                                     save_checkpoint)
 
@@ -149,9 +150,15 @@ def prefetch_to_device(chunks: Iterable, put: Callable, depth: int = 2
     H2D copies overlap device compute.  ``depth <= 0`` degrades to a plain
     synchronous map (useful for debugging)."""
     if depth <= 0:
-        for c in chunks:
-            yield put(c)
-        return
+        it, end = iter(chunks), object()
+        while True:
+            with span("engine.wait_chunk"):
+                c = next(it, end)
+                if c is end:
+                    return
+                with span("engine.place"):
+                    placed = put(c)
+            yield placed
     q: queue.Queue = queue.Queue(maxsize=depth)
     sentinel = object()
     stop = threading.Event()
@@ -170,7 +177,11 @@ def prefetch_to_device(chunks: Iterable, put: Callable, depth: int = 2
     def producer():
         try:
             for c in chunks:
-                if stop.is_set() or not _put(put(c)):
+                if stop.is_set():
+                    return
+                with span("engine.place"):
+                    placed = put(c)
+                if not _put(placed):
                     return
         except BaseException as e:  # noqa: BLE001 — re-raised on the consumer
             errors.append(e)
@@ -182,7 +193,8 @@ def prefetch_to_device(chunks: Iterable, put: Callable, depth: int = 2
     t.start()
     try:
         while True:
-            item = q.get()
+            with span("engine.wait_chunk"):
+                item = q.get()
             if item is sentinel:
                 break
             yield item
@@ -204,19 +216,26 @@ def prefetch_to_device(chunks: Iterable, put: Callable, depth: int = 2
 
 
 def _as_host_dict(batch) -> dict:
-    if dataclasses.is_dataclass(batch) and not isinstance(batch, dict):
-        d = dataclasses.asdict(batch)
-    else:
-        d = dict(batch)
-    # Optional batch fields (the SSLBatch tile layout when the pipeline has
-    # no layout_bt) are None — drop them so chunk stacking and device
-    # placement only ever see arrays.
-    return {k: v for k, v in d.items() if v is not None}
+    with span("engine.to_host"):
+        if dataclasses.is_dataclass(batch) and not isinstance(batch, dict):
+            d = dataclasses.asdict(batch)
+        else:
+            d = dict(batch)
+        # Optional batch fields (the SSLBatch tile layout when the pipeline
+        # has no layout_bt) are None — drop them so chunk stacking and
+        # device placement only ever see arrays.
+        return {k: v for k, v in d.items() if v is not None}
+
+
+def _steps(placed) -> int:
+    """Steps in a placed chunk (its leading, scan axis)."""
+    return int(jax.tree_util.tree_leaves(placed)[0].shape[0])
 
 
 def _stack_chunk(batches: list[dict]) -> dict:
     """Stack per-step host batches into one (S, ...) scan chunk."""
-    return {k: np.stack([b[k] for b in batches]) for k in batches[0]}
+    with span("engine.stack"):
+        return {k: np.stack([b[k] for b in batches]) for k in batches[0]}
 
 
 # ---------------------------------------------------------------- strategies
@@ -359,8 +378,9 @@ class AsyncPSStrategy:
                 lambda g: (g * scale).astype(g.dtype), grads)
             metrics = dict(metrics)
             metrics["async/dropped"] = 1.0 - jnp.where(live[w], 1.0, 0.0)
-        params, opt_state = self.engine.opt.update(
-            grads, state.opt_state, state.params, lr)
+        with scope("optimizer"):
+            params, opt_state = self.engine.opt.update(
+                grads, state.opt_state, state.params, lr)
         ages = ages.at[w].add(1)
         refresh = ages[w] >= self.max_staleness
         snapshots = jax.tree.map(
@@ -512,6 +532,7 @@ class Engine:
 
         return body
 
+    @scope("chunk")
     def _run_chunk(self, carry, batches, lr, capture: bool = False):
         """The hot path.  With the guard on the scan body is *identical* to
         the unguarded one — no per-step check, count, or select.  The only
@@ -765,7 +786,7 @@ class Engine:
         for epoch in range(start, n_epochs):
             lr = jnp.float32(lr_schedule(epoch))
             cap = capture_on(epoch)
-            t0 = time.time()
+            t0 = time.perf_counter()
             sc, gs = self._split_carry(carry)
             carry = self._wrap_carry(strategy.begin_epoch(sc), gs)
             metric_chunks = []
@@ -797,8 +818,9 @@ class Engine:
                 carry = self._bump(strategy, carry, bumps.get(item[0]))
                 # The window's first chunk must not donate its input: the
                 # backup has to survive for a possible strict replay.
-                carry, item[2] = (self._chunk_keep if first else
-                                  self._chunk_fn)(carry, item[1], lr, cap)
+                with span("engine.dispatch", steps=_steps(item[1])):
+                    carry, item[2] = (self._chunk_keep if first else
+                                      self._chunk_fn)(carry, item[1], lr, cap)
                 win.append(item)
                 if len(win) == self._guard_window:
                     done.append((win_backup, win[:], carry))
@@ -808,9 +830,10 @@ class Engine:
                 nonlocal carry
                 backup, items, out = done.popleft()
                 gs = self._split_carry(out)[1]
-                skipped, worst, tainted = (
-                    v.item() for v in
-                    jax.device_get((gs[0], gs[2], gs[3])))
+                with span("engine.guard_fetch"):
+                    skipped, worst, tainted = (
+                        v.item() for v in
+                        jax.device_get((gs[0], gs[2], gs[3])))
                 if tainted:
                     # Non-finite step(s) somewhere in this window: discard
                     # the hot pass and replay the window strictly from its
@@ -820,7 +843,9 @@ class Engine:
                     cur = backup
                     for item in items:
                         cur = self._bump(strategy, cur, bumps.get(item[0]))
-                        cur, item[2] = self._strict_fn(cur, item[1], lr, cap)
+                        with span("engine.dispatch", steps=_steps(item[1])):
+                            cur, item[2] = self._strict_fn(cur, item[1], lr,
+                                                           cap)
                     gs = self._split_carry(cur)[1]
                     skipped, worst = (int(v) for v in
                                       jax.device_get((gs[0], gs[2])))
@@ -851,7 +876,8 @@ class Engine:
                         bumps[chunk_idx] = (ev.worker, ev.arg)
                 if not self._guard:
                     carry = self._bump(strategy, carry, bumps.get(chunk_idx))
-                    carry, metrics = self._chunk_fn(carry, placed, lr, cap)
+                    with span("engine.dispatch", steps=_steps(placed)):
+                        carry, metrics = self._chunk_fn(carry, placed, lr, cap)
                     metric_chunks.append(metrics)   # fetched after the epoch
                     continue
                 dispatch([chunk_idx, placed, None])
@@ -862,40 +888,48 @@ class Engine:
                     done.append((win_backup, win[:], carry))
                     win.clear()
                 resolve_window()
-            if not metric_chunks:
-                # e.g. n_meta < n_workers: the pipeline had nothing to yield.
-                warnings.warn(
-                    f"epoch {epoch}: pipeline yielded no batches "
-                    "(n_meta < n_workers?); skipping epoch row", stacklevel=2)
-                continue
-            captures = None
-            if cap:
-                # Pull the tap out of the metric chunks (it must not enter
-                # the row means) and stack it (total_steps, ...) on host.
-                captures = np.concatenate(
-                    [np.asarray(jax.device_get(mc.pop(self._CAPTURE_KEY)))
-                     for mc in metric_chunks])
-            row = {
-                k: float(np.mean(np.concatenate(
-                    [np.asarray(mc[k]) for mc in metric_chunks])))
-                for k in metric_chunks[0]
-            }
-            row.update(epoch=epoch, lr=float(lr), seconds=time.time() - t0)
-            if self._guard:
-                row["guard/skipped_total"] = int(
-                    jax.device_get(self._split_carry(carry)[1][0]))
-            if eval_fn is not None:
-                row.update(eval_fn(
-                    strategy.state_of(self._split_carry(carry)[0]).params))
-            history.append(row)
-            if on_epoch_end is not None:
-                on_epoch_end(
-                    epoch,
-                    strategy.state_of(self._split_carry(carry)[0]).params,
-                    captures)
-            if self.checkpoint_every and \
-                    (epoch + 1) % self.checkpoint_every == 0:
-                self._save(carry, epoch + 1, history)
+            with span("engine.epoch_end"):
+                if not metric_chunks:
+                    # e.g. n_meta < n_workers: the pipeline had nothing to
+                    # yield.
+                    warnings.warn(
+                        f"epoch {epoch}: pipeline yielded no batches "
+                        "(n_meta < n_workers?); skipping epoch row",
+                        stacklevel=2)
+                    continue
+                with span("engine.metrics_fetch"):
+                    captures = None
+                    if cap:
+                        # Pull the tap out of the metric chunks (it must not
+                        # enter the row means) and stack it (total_steps, ...)
+                        # on host.
+                        captures = np.concatenate(
+                            [np.asarray(jax.device_get(
+                                mc.pop(self._CAPTURE_KEY)))
+                             for mc in metric_chunks])
+                    row = {
+                        k: float(np.mean(np.concatenate(
+                            [np.asarray(mc[k]) for mc in metric_chunks])))
+                        for k in metric_chunks[0]
+                    }
+                    row.update(epoch=epoch, lr=float(lr),
+                               seconds=time.perf_counter() - t0)
+                    if self._guard:
+                        row["guard/skipped_total"] = int(
+                            jax.device_get(self._split_carry(carry)[1][0]))
+                if eval_fn is not None:
+                    with span("engine.eval"):
+                        row.update(eval_fn(strategy.state_of(
+                            self._split_carry(carry)[0]).params))
+                history.append(row)
+                if on_epoch_end is not None:
+                    with span("engine.on_epoch_end"):
+                        on_epoch_end(epoch, strategy.state_of(
+                            self._split_carry(carry)[0]).params, captures)
+                if self.checkpoint_every and \
+                        (epoch + 1) % self.checkpoint_every == 0:
+                    with span("engine.checkpoint"):
+                        self._save(carry, epoch + 1, history)
         return EngineResult(
             state=strategy.state_of(self._split_carry(carry)[0]),
             history=history)

@@ -23,6 +23,7 @@ from repro.models import transformer as tf
 from repro.models.config import ModelConfig
 from repro.models.dnn import DNNConfig, dnn_forward
 from repro.optim import Optimizer
+from repro.tracing import scope
 
 Array = jax.Array
 
@@ -57,8 +58,9 @@ def dnn_ssl_loss(params, batch: dict, cfg: DNNConfig, hyper: SSLHyper,
                  else [])
 
     def per_worker(params, dropout_rng, x, y, mask, W, valid, *tiles):
-        logits = dnn_forward(params, x, dropout_rng=dropout_rng,
-                             dropout=dropout)
+        with scope("dnn"):
+            logits = dnn_forward(params, x, dropout_rng=dropout_rng,
+                                 dropout=dropout)
         # Padding rows: zero affinity + zero label mask + masked entropy term.
         mask = mask * valid
         Wm = W * valid[:, None] * valid[None, :]
@@ -106,7 +108,8 @@ def dnn_ssl_step(params, opt_state, batch: dict, *, cfg: DNNConfig,
     grads, metrics = dnn_ssl_grads(params, batch, cfg=cfg, hyper=hyper,
                                    dropout_rng=dropout_rng, dropout=dropout,
                                    pairwise=pairwise, mesh=mesh)
-    new_params, new_state = opt.update(grads, opt_state, params, lr)
+    with scope("optimizer"):
+        new_params, new_state = opt.update(grads, opt_state, params, lr)
     return new_params, new_state, metrics
 
 

@@ -58,9 +58,14 @@ def no_persistent_cache():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernels=()):
+    """Compile ``fn``; its Mosaic kernels carry the fixed ``name=`` of each
+    of ``kernels`` as their HLO instruction name (what a trace shows)."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for name in kernels:
+        assert f"%{name}" in text, name
     return compiled
 
 
@@ -82,7 +87,7 @@ def test_fused_forward_compiles(one_chip):
     compiled = _compile(
         lambda lp, w: graph_reg_fused_pallas(lp, w, 1.0, 1e-4,
                                              interpret=False, **_reg_tiles()),
-        s(P, C), s(P, P))
+        s(P, C), s(P, P), kernels=["graph_reg_fused_reg_forward"])
     # The kernel streams W tile by tile: no B×B temporary.
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * P * P
 
@@ -92,7 +97,7 @@ def test_cross_term_forward_compiles(one_chip):
                                             sharding=one_chip)
     _compile(lambda lp, w: graph_reg_pairwise_pallas(lp, w, bc=C,
                                                      interpret=False),
-             s(P, C), s(P, P))
+             s(P, C), s(P, P), kernels=["graph_reg_cross"])
 
 
 def test_fused_backward_compiles(one_chip):
@@ -100,7 +105,8 @@ def test_fused_backward_compiles(one_chip):
                                             sharding=one_chip)
     _compile(lambda lp, w, g: graph_reg_bwd_pallas(
         lp, w, g, gamma=1.0, kappa=1e-4, ent_weight=1.0, interpret=False,
-        **_reg_tiles()), s(P, C), s(P, P), s())
+        **_reg_tiles()), s(P, C), s(P, P), s(),
+        kernels=["graph_reg_bwd_dlogp", "graph_reg_bwd_dw"])
 
 
 def test_blocksparse_forward_compiles(one_chip):
@@ -109,7 +115,8 @@ def test_blocksparse_forward_compiles(one_chip):
     rows, cols, valid = _layout_shapes(one_chip)[:3]
     _compile(lambda lp, w, r, c, v: graph_reg_blocksparse_pallas(
         lp, w, r, c, v, 1.0, 1e-4, bt=BT, bc=C, interpret=False),
-        s(P, C), s(P, P), rows, cols, valid)
+        s(P, C), s(P, P), rows, cols, valid,
+        kernels=["graph_reg_bsp_forward"])
 
 
 def test_blocksparse_backward_compiles(one_chip):
@@ -118,7 +125,9 @@ def test_blocksparse_backward_compiles(one_chip):
     layout = _layout_shapes(one_chip)
     _compile(lambda lp, w, g, *lay: graph_reg_blocksparse_bwd_pallas(
         lp, w, g, *lay, gamma=1.0, kappa=1e-4, ent_weight=1.0, bt=BT, bc=C,
-        interpret=False), s(P, C), s(P, P), s(), *layout)
+        interpret=False), s(P, C), s(P, P), s(), *layout,
+        kernels=["graph_reg_bsp_bwd_bterm", "graph_reg_bsp_bwd_dlogp",
+                 "graph_reg_bsp_bwd_dw"])
 
 
 def test_knn_topk_compiles(one_chip):
@@ -126,7 +135,8 @@ def test_knn_topk_compiles(one_chip):
     x = jax.ShapeDtypeStruct((N_GRAPH, D), jnp.float32, sharding=one_chip)
     _compile(lambda x: knn_topk_pallas(x, x, 10, exclude_self=True,
                                        bi=t.bi, bj=t.bj, bd=t.bd,
-                                       interpret=False), x)
+                                       interpret=False), x,
+             kernels=["knn_topk"])
 
 
 def test_sync_mesh_chunk_compiles_on_four_chips(topo, monkeypatch):
