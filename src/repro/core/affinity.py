@@ -33,18 +33,71 @@ patching — so new users join the live graph without an O(N²) rebuild
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import numpy as np
 import scipy.sparse as sp
 
 __all__ = [
     "AffinityGraph",
+    "SparseBlock",
     "pairwise_sq_dists",
     "knn_edges",
     "build_affinity_graph",
     "insert_nodes",
     "evict_nodes",
 ]
+
+
+class SparseBlock:
+    """A float32 array of ``shape`` held as its nonzero entries.
+
+    ``index`` holds the entries' flat (C-order) positions in ``shape`` and
+    ``vals`` their values; every other element is zero.  ``np.asarray``
+    gives the dense array, and ``scatter_into`` writes the entries into a
+    zeroed array in place, so a consumer that owns the destination (the
+    engine's chunk buffer) materialises the block exactly once.
+    """
+
+    dtype = np.dtype(np.float32)
+
+    def __init__(self, shape, index: np.ndarray, vals: np.ndarray):
+        self.shape = tuple(int(s) for s in shape)
+        self.index = np.asarray(index, np.int64)
+        self.vals = np.asarray(vals, np.float32)
+
+    @property
+    def ndim(self) -> int:
+        return len(self.shape)
+
+    @classmethod
+    def stack(cls, blocks) -> "SparseBlock":
+        """Blocks of one shape stacked along a new leading axis."""
+        shape = blocks[0].shape
+        if any(b.shape != shape for b in blocks):
+            raise ValueError("SparseBlock.stack needs blocks of one shape, "
+                             f"got {[b.shape for b in blocks]}")
+        size = math.prod(shape)
+        return cls((len(blocks),) + shape,
+                   np.concatenate([b.index + i * size
+                                   for i, b in enumerate(blocks)]),
+                   np.concatenate([b.vals for b in blocks]))
+
+    def scatter_into(self, out: np.ndarray) -> None:
+        """Write the entries into ``out``, a zeroed C-contiguous array of
+        ``shape``; the zeros are left as they are."""
+        if out.shape != self.shape or not out.flags.c_contiguous:
+            raise ValueError(f"scatter_into needs a C-contiguous {self.shape} "
+                             f"array, got {out.shape}")
+        out.reshape(-1)[self.index] = self.vals
+
+    def __array__(self, dtype=None, copy=None):
+        out = np.zeros(self.shape, self.dtype)
+        self.scatter_into(out)
+        return out if dtype is None else out.astype(dtype, copy=False)
+
+    def __getitem__(self, key):
+        return np.asarray(self)[key]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,6 +142,20 @@ class AffinityGraph:
         """Dense ``|idx| x |idx|`` affinity sub-block for a (meta-)batch."""
         sub = self.W[idx][:, idx]
         return np.asarray(sub.todense(), dtype=np.float32)
+
+    def sparse_block(self, idx: np.ndarray,
+                     size: int | None = None) -> SparseBlock:
+        """The ``dense_block(idx)`` zero-padded to ``size x size`` (default
+        ``|idx|``), held as its nonzero entries: the same float32 values
+        from O(nnz) work instead of O(size^2)."""
+        size = len(idx) if size is None else int(size)
+        if size < len(idx):
+            raise ValueError(f"size {size} < block rows {len(idx)}")
+        sub = self.W[idx][:, idx].tocoo()
+        sub.sum_duplicates()
+        return SparseBlock((size, size),
+                           sub.row.astype(np.int64) * size + sub.col,
+                           sub.data.astype(np.float32))
 
     def insert(self, X: np.ndarray, X_new: np.ndarray,
                **kw) -> "AffinityGraph":

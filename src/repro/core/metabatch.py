@@ -24,7 +24,7 @@ import scipy.sparse as sp
 
 from repro.introspect import accepts_kwarg
 
-from .affinity import AffinityGraph
+from .affinity import AffinityGraph, SparseBlock
 from .partition import PartitionResult, edge_cut, partition_graph
 
 __all__ = ["MetaBatchPlan", "build_mini_blocks", "synthesize_meta_batches",
@@ -382,17 +382,24 @@ class BlockLayout:
                 self.crows, self.ccols, self.cvalid, self.occ)
 
 
-def tile_occupancy(W: np.ndarray, bt: int) -> np.ndarray:
+def tile_occupancy(W: np.ndarray | SparseBlock, bt: int) -> np.ndarray:
     """(nt, nt) bool mask: tile (i, j) is True iff any W entry in it is != 0.
 
     Exact occupancy — the block-sparse regularizer over this mask equals
     the dense regularizer bit-for-bit semantics-wise (a skipped tile is an
-    all-zero tile, contributing nothing to any Eq.-3/4 term).
+    all-zero tile, contributing nothing to any Eq.-3/4 term).  A
+    :class:`SparseBlock` marks the tiles of its nonzero entries,
+    ``(row // bt, col // bt)``, without densifying.
     """
     if W.ndim != 2 or W.shape[0] != W.shape[1]:
         raise ValueError(f"W must be square, got shape {W.shape}")
     B = W.shape[0]
     nt = -(-B // bt)
+    if isinstance(W, SparseBlock):
+        rows, cols = np.divmod(W.index[W.vals != 0], B)
+        occ = np.zeros((nt, nt), dtype=bool)
+        occ[rows // bt, cols // bt] = True
+        return occ
     P = nt * bt
     if P != B:
         Wp = np.zeros((P, P), dtype=W.dtype)
@@ -468,9 +475,9 @@ def layout_from_occupancy(
 
 
 def block_layout(
-    W: np.ndarray, bt: int, *, list_len: int | None = None
+    W: np.ndarray | SparseBlock, bt: int, *, list_len: int | None = None
 ) -> BlockLayout:
-    """BlockLayout of a (padded) dense batch affinity block W."""
+    """BlockLayout of a (padded) batch affinity block W."""
     return layout_from_occupancy(tile_occupancy(W, bt), bt,
                                  list_len=list_len)
 

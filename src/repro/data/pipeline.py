@@ -1,8 +1,10 @@
 """Training-batch pipeline: meta-batches -> device-ready arrays.
 
 Each step yields the concatenated batch ``M_c = [M_r, M_s]`` of §2.3:
-features, labels, label mask, and the dense affinity sub-block ``W`` for the
-concatenated index set.  For ``k``-worker data parallelism, each step packs
+features, labels, label mask, and the affinity sub-block ``W`` for the
+concatenated index set, held as its nonzero entries (a ``SparseBlock``:
+``np.asarray`` gives it dense; the engine scatters it straight into its
+chunk buffer).  For ``k``-worker data parallelism, each step packs
 ``k`` independent concatenated batches along a leading axis — the launcher
 shards that axis over the mesh's data dimension, which *is* the paper's
 Eq.-7 parallel decomposition.
@@ -30,7 +32,7 @@ from typing import Iterator
 
 import numpy as np
 
-from repro.core.affinity import AffinityGraph
+from repro.core.affinity import AffinityGraph, SparseBlock
 from repro.core.metabatch import (MetaBatchPlan, NeighborSampler,
                                   block_layout, epoch_plan_seed,
                                   plan_layout_budget, resynthesize_plan)
@@ -51,7 +53,7 @@ class SSLBatch:
     x: np.ndarray            # (k, P, d)    P = padded concat-batch size
     y: np.ndarray            # (k, P)
     label_mask: np.ndarray   # (k, P) float {0,1}
-    W: np.ndarray            # (k, P, P) dense affinity block
+    W: np.ndarray | SparseBlock  # (k, P, P) f32 affinity block
     valid: np.ndarray        # (k, P) bool (padding indicator)
     # Optional block-sparse layout of W (``BlockLayout.arrays()`` per
     # worker, stacked along k) — present only when the pipeline was built
@@ -78,7 +80,8 @@ def _pad_to(a: np.ndarray, size: int, axis: int = 0) -> np.ndarray:
 def _assemble(corpus: SyntheticCorpus, graph: AffinityGraph,
               idx: np.ndarray, P: int, *, layout_bt: int | None = None,
               layout_len: int | None = None):
-    """Padded (x, y, label_mask, W, valid) arrays for one concat batch.
+    """Padded (x, y, label_mask, W, valid) arrays for one concat batch;
+    ``W`` is a :class:`SparseBlock` of the padded (P, P) block.
 
     With ``layout_bt`` the tuple is extended by the 7 ``BlockLayout``
     arrays of the padded W (``layout_len`` pins the static tile-list
@@ -88,7 +91,7 @@ def _assemble(corpus: SyntheticCorpus, graph: AffinityGraph,
     """
     with span("pipeline.block"):
         with span("pipeline.densify"):
-            W = _pad_to(_pad_to(graph.dense_block(idx), P, 0), P, 1)
+            W = graph.sparse_block(idx, P)
         base = (_pad_to(corpus.X[idx], P),
                 _pad_to(corpus.y[idx], P),
                 _pad_to(corpus.label_mask[idx].astype(np.float32), P),
@@ -101,7 +104,9 @@ def _assemble(corpus: SyntheticCorpus, graph: AffinityGraph,
 
 def _stack_group(parts) -> SSLBatch:
     with span("pipeline.stack"):
-        cols = [np.stack(c) for c in zip(*parts)]
+        cols = [SparseBlock.stack(c)
+                if all(isinstance(a, SparseBlock) for a in c) else np.stack(c)
+                for c in zip(*parts)]
     return SSLBatch(*cols)   # 5 base columns, +7 tile columns with a layout
 
 

@@ -30,14 +30,19 @@ producer thread):
 * ``replan.synthesize``: one plan synthesis, on the replan thread (or
   inside ``pipeline.epoch_begin`` when it runs synchronously).
 * ``pipeline.block``: one worker's concatenated block, padded; its child
-  ``pipeline.densify`` is the dense affinity block and both pads of W.
-* ``pipeline.stack``: stacking a step's k blocks.
+  ``pipeline.densify`` is the work on W: extracting the padded block's
+  nonzero entries from the graph's CSR (W is densified later, once, by
+  ``engine.stack``).
+* ``pipeline.stack``: stacking a step's k blocks (W's entries joined).
 
 Engine (``train/engine.py``):
 
-* ``engine.to_host``: a step's batch copied into a host dict (producer).
+* ``engine.to_host``: a step's batch read into a host dict, its arrays
+  shared, not copied (producer).
 * ``engine.stack``: a chunk's steps stacked into one (S, ...) array per
-  field (producer).
+  field: W's entries scattered into the zeroed chunk buffer, a dense W
+  and every other field copied in (producer); stats ``w_scattered`` and
+  ``w_copied``, the chunk's (P, P) affinity blocks written each way.
 * ``engine.place``: a chunk put on the device, with any supervisor or
   fault-injector wrapper (producer; in the consumer when prefetch is 0).
 * ``engine.wait_chunk``: the training loop waiting for its next placed

@@ -36,6 +36,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import json
+import math
 import os
 import queue
 import threading
@@ -48,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.affinity import SparseBlock
 from repro.introspect import accepts_kwarg
 from repro.resilience.guard import NonFiniteHaltError, all_finite, guard_init
 from repro.resilience.supervisor import Supervisor
@@ -218,7 +220,10 @@ def prefetch_to_device(chunks: Iterable, put: Callable, depth: int = 2
 def _as_host_dict(batch) -> dict:
     with span("engine.to_host"):
         if dataclasses.is_dataclass(batch) and not isinstance(batch, dict):
-            d = dataclasses.asdict(batch)
+            # The batch's own arrays, not copies: the chunk stacking below
+            # writes every field into its chunk buffer once.
+            d = {f.name: getattr(batch, f.name)
+                 for f in dataclasses.fields(batch)}
         else:
             d = dict(batch)
         # Optional batch fields (the SSLBatch tile layout when the pipeline
@@ -232,10 +237,40 @@ def _steps(placed) -> int:
     return int(jax.tree_util.tree_leaves(placed)[0].shape[0])
 
 
+def _stack_steps(vals: list):
+    """One field's per-step values stacked into an (S, ...) array.  A step
+    held as a :class:`SparseBlock` is scattered into its zeroed slot, so
+    its dense form is written exactly once; a dense step is copied in."""
+    if not any(isinstance(v, SparseBlock) for v in vals):
+        return np.stack(vals)
+    shape = vals[0].shape
+    if any(v.shape != shape for v in vals):
+        raise ValueError(f"steps of one field differ in shape: "
+                         f"{[v.shape for v in vals]}")
+    out = np.zeros((len(vals),) + shape,
+                   np.result_type(*(v.dtype for v in vals)))
+    for slot, v in zip(out, vals):
+        if isinstance(v, SparseBlock):
+            v.scatter_into(slot)
+        else:
+            slot[...] = v
+    return out
+
+
 def _stack_chunk(batches: list[dict]) -> dict:
-    """Stack per-step host batches into one (S, ...) scan chunk."""
-    with span("engine.stack"):
-        return {k: np.stack([b[k] for b in batches]) for k in batches[0]}
+    """Stack per-step host batches into one (S, ...) scan chunk.  The
+    span's stats count the chunk's (P, P) affinity blocks by how they were
+    written: ``w_scattered`` from entries, ``w_copied`` from dense arrays."""
+    with span("engine.stack") as sp:
+        chunk = {k: _stack_steps([b[k] for b in batches])
+                 for k in batches[0]}
+        if "W" in batches[0]:
+            blocks = [(isinstance(b["W"], SparseBlock),
+                       math.prod(b["W"].shape[:-2])) for b in batches]
+            sp.set_metadata(
+                w_scattered=sum(n for sparse, n in blocks if sparse),
+                w_copied=sum(n for sparse, n in blocks if not sparse))
+        return chunk
 
 
 # ---------------------------------------------------------------- strategies

@@ -64,6 +64,23 @@ def test_dense_block_matches_csr(small_graph_setup):
     np.testing.assert_allclose(blk, ref, atol=1e-7)
 
 
+@pytest.mark.parametrize("size", [None, 64])
+def test_sparse_block_scatters_to_dense_block(small_graph_setup, size):
+    _, graph, _ = small_graph_setup
+    idx = np.concatenate([np.arange(0, 60, 2), np.arange(300, 320)])
+    dense = graph.dense_block(idx)
+    blk = graph.sparse_block(idx, size)
+    n = len(idx) if size is None else size
+    assert blk.shape == (n, n) and blk.vals.dtype == np.float32
+    assert len(blk.vals) == np.count_nonzero(dense)
+    out = np.zeros((n, n), np.float32)
+    blk.scatter_into(out)
+    want = np.zeros((n, n), np.float32)
+    want[:len(idx), :len(idx)] = dense
+    assert out.tobytes() == want.tobytes()
+    assert np.asarray(blk).tobytes() == want.tobytes()
+
+
 # ---------------------------------------------------------------- partition
 def test_partition_balanced_and_better_than_random(small_graph_setup):
     _, graph, _ = small_graph_setup
