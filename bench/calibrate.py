@@ -3,17 +3,19 @@
     python3 bench/calibrate.py --workload <name> --seeds 1,2,... \\
         [--control-seeds 3] [--out FILE]
 
-On the chip, in one process: for every seed, the cell's program is driven
-from the seed through its first scan chunk (the steps ``bench/run.py``
-checks), and its numbers against the reference are printed as one JSON
-line.  For the first ``--control-seeds`` seeds it also prints the numbers
-of each control (``CONTROLS`` of ``references/<name>.py``: the reference
-below the stated precision, in the program's place, in the step and in
-the regularizer kernels), of each fault the cell can have (planted in the
-reference put in the program's place) and of a batch whose affinity block pairs the neighbour rows with the
-wrong columns (``batch.wrong_neighbours``).  A summary line gives, per
-number, the largest sound reading and the smallest reading of each
-control and fault.
+On the chip, in one process: for every seed, the cell's program is
+driven from the seed through its first scan chunk (the steps
+``bench/run.py`` checks), and its numbers against the reference (the
+checks of the cell's family module, ``cell.family``) are printed as one
+JSON line. For the first ``--control-seeds`` seeds it also prints the
+numbers of each control (``CONTROLS`` of ``references/<name>.py``: the
+reference below the stated precision, in the program's place, in the
+step and in the regularizer kernels), of each fault the cell can have
+(planted in the reference put in the program's place) and of a batch
+whose affinity block pairs the neighbour rows with the wrong columns
+(``batch.wrong_neighbours``). A summary line gives, per number, the
+largest sound reading and the smallest reading of each control and
+fault.
 """
 from __future__ import annotations
 
@@ -74,6 +76,7 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     harness.setup_jax()
+    family = cell.family
     ref = harness.load_module("references", cell.config["reference"])
     faults = [f for f in ref.FAULTS
               if f != "no_exchange" or cell.config["n_workers"] > 1]
@@ -88,22 +91,22 @@ def main(argv=None) -> int:
         del probe
         gc.collect()
         row = {"seed": seed, "pad": info["pad"],
-               "program": harness.reference_numbers(
+               "program": family.reference_numbers(
                    cell, seed, first_chunk, first, detail=True)}
-        row["program"].update(harness.kernel_check(cell, seed, first_chunk))
-        row["program"].update(harness.batch_check(cell, info, first_chunk))
+        row["program"].update(family.kernel_check(cell, seed, first_chunk))
+        row["program"].update(family.batch_check(cell, info, first_chunk))
         if i < args.control_seeds:
             for name, kw in ref.CONTROLS.items():
-                row[name] = harness.reference_numbers(
+                row[name] = family.reference_numbers(
                     cell, seed, first_chunk, first, detail=True,
                     **kw["step"])
-                row[name].update(harness.kernel_check(
+                row[name].update(family.kernel_check(
                     cell, seed, first_chunk, **kw["kernel"]))
             for fault in faults:
-                row[fault] = harness.reference_numbers(
+                row[fault] = family.reference_numbers(
                     cell, seed, first_chunk, first, detail=True,
                     fault=fault)
-            row["batch.wrong_neighbours"] = harness.batch_check(
+            row["batch.wrong_neighbours"] = family.batch_check(
                 cell, info, wrong_neighbours(first_chunk))
         del first_chunk, first, info
         gc.collect()

@@ -1,9 +1,18 @@
 """One run of one benchmark cell: build, warm up, measure, check.
 
 The cell's configuration and traffic files (named in ``BENCHMARK.json``)
-become one ``ExperimentConfig``; the run then drives the program's normal
-path, ``Experiment.run`` -> ``Engine.run`` -> ``dnn_ssl_loss`` -> the
-PAIRWISE kernels, with the prefetching ``MetaBatchStream`` producer on.
+become one ``ExperimentConfig`` through the configuration's family module
+(``bench/families/<reference>.py``, ``cell.family``); the run then drives
+the program's normal path, ``Experiment.run`` -> ``Engine.run`` -> the
+family's loss -> the PAIRWISE kernels, with the prefetching
+``MetaBatchStream`` producer on.  This module knows only the graph-SSL
+contract that every family shares (``bench/families/__init__.py``): what
+``Experiment`` and ``Engine`` provide, every batch's ``valid`` of shape
+(k, P), the chunk metrics ``loss/total`` and ``loss/graph``, and the keys
+``pad_rows``, ``prefetch`` and ``repartition_every_n_epochs`` of the
+traffic.  Whatever knows the model (its ``ExperimentConfig``, the held-out
+eval, its state on the host, the checks against its reference, its FLOPs)
+is the family's.
 
 The benchmark watches that path from outside, through wrappers it puts
 around its calls for the length of the run (``instrument``):
@@ -21,13 +30,15 @@ around its calls for the length of the run (``instrument``):
 * The pipeline's epoch iterator (batch assembly, one ``next()`` per step),
   the engine's grouping of steps into chunks, the strategy's
   ``place_batch`` (host -> device) and the held-out eval get host spans
-  (``assemble``, ``host_chunk``, ``place``, ``eval``) and counts.  Spans
+  (``assemble``, ``host_chunk``, ``place``, ``eval``; the eval call is
+  the one the family names in ``EVAL``) and counts.  Spans
   also go to the profiler trace through ``jax.profiler.TraceAnnotation``,
   named ``bench.<span>``.
 
 After the window, with the program's threads joined, the check that
-decides ``correct`` (``bench/correctness.py``) compares the first chunk
-with the plain reference (``bench/references/<name>.py``): the steps
+decides ``correct`` (the family's ``reference_numbers``, ``kernel_check``
+and ``batch_check``, by ``bench/correctness.py``) compares the first chunk
+with the plain reference (``bench/references/<reference>.py``): the steps
 (losses, regularizer values, gradient and update norms), the regularizer
 kernels at the timed shapes, the batches against the corpus and the
 reference's own k-NN graph, and the replans the run owed against those
@@ -37,8 +48,8 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import gc
+import importlib.util
 import json
 import math
 import os
@@ -78,6 +89,7 @@ class Cell:
     limits: dict        # bench/limits/<cell>.json
     end_to_end: list    # BENCHMARK.json metric entries this cell reports
     per_layer: list
+    family: object      # bench/families/<reference>.py
 
 
 def reports(metric: dict, cell: str, end_to_end: list) -> bool:
@@ -93,6 +105,9 @@ def reports(metric: dict, cell: str, end_to_end: list) -> bool:
 
 
 def load_cell(name: str, root: str = ROOT) -> Cell:
+    """The cell ``name`` of ``<root>/BENCHMARK.json``, every file of it read
+    under ``root``: configuration, traffic, limits and family module."""
+    bench_dir = os.path.join(root, os.path.basename(BENCH_DIR))
     spec = load_json(os.path.join(root, "BENCHMARK.json"))
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
@@ -104,13 +119,19 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
            if reports(m, name, spec["end_to_end"])]
     layer = [m for m in spec["per_layer"]
              if reports(m, name, spec["end_to_end"])]
+    config = load_json(os.path.join(root, conf["file"]))
+    family = os.path.join(bench_dir, "families", config["reference"] + ".py")
+    if not os.path.exists(family):
+        raise FileNotFoundError(
+            f"configuration {conf['name']!r} names the family "
+            f"{config['reference']!r}, but there is no {family}")
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=load_json(os.path.join(root, conf["file"])),
-        traffic=load_json(os.path.join(BENCH_DIR, "traffic",
+        name=name, chips=int(w["chips"]), config=config,
+        traffic=load_json(os.path.join(bench_dir, "traffic",
                                        w["traffic"] + ".json")),
-        limits=load_json(os.path.join(BENCH_DIR, "limits", name + ".json")),
-        end_to_end=e2e, per_layer=layer)
+        limits=load_json(os.path.join(bench_dir, "limits", name + ".json")),
+        end_to_end=e2e, per_layer=layer,
+        family=load_module("families", config["reference"], bench_dir))
 
 
 def seeds_of(seed: int) -> dict:
@@ -119,41 +140,6 @@ def seeds_of(seed: int) -> dict:
     words = np.random.SeedSequence(int(seed)).generate_state(3)
     data, partition, model = (int(w) & 0x7FFFFFFF for w in words)
     return {"data": data, "partition": partition, "model": model}
-
-
-def experiment_config(config: dict, traffic: dict, seed: int):
-    """The ``ExperimentConfig`` of a configuration under a traffic mix."""
-    from repro.api import (BatchConfig, DataConfig, ExecutionConfig,
-                           ExperimentConfig, GraphConfig, ObjectiveConfig,
-                           RepartitionConfig, TrainConfig)
-    s = seeds_of(seed)
-    c, t = config, traffic
-    return ExperimentConfig(
-        name=c["name"],
-        data=DataConfig(n=c["n_frames"], input_dim=c["input_dim"],
-                        n_classes=c["n_classes"],
-                        manifold_dim=c["manifold_dim"],
-                        structure=c["structure"],
-                        label_ratio=c["label_ratio"],
-                        test_fraction=c["test_fraction"], seed=s["data"]),
-        graph=GraphConfig(k=c["k_neighbours"],
-                          construction=c["graph_construction"]),
-        batch=BatchConfig(batch_size=t["batch_size"], pipeline=t["pipeline"],
-                          with_neighbor=t["with_neighbor"],
-                          layout_bt=t["layout_bt"]),
-        repartition=RepartitionConfig(
-            every_n_epochs=t["repartition_every_n_epochs"],
-            seed=s["partition"]),
-        objective=ObjectiveConfig(gamma=c["gamma"], kappa=c["kappa"],
-                                  weight_decay=c["weight_decay"],
-                                  pairwise=t["pairwise"]),
-        train=TrainConfig(hidden_dim=c["hidden_dim"], n_hidden=c["n_hidden"],
-                          dropout=c["dropout"], optimizer=c["optimizer"],
-                          base_lr=c["base_lr"], n_workers=c["n_workers"],
-                          n_epochs=t["max_epochs"], seed=s["model"]),
-        execution=ExecutionConfig(strategy=c["strategy"],
-                                  scan_chunk=t["scan_chunk"],
-                                  prefetch=t["prefetch"]))
 
 
 # ------------------------------------------------------------------ spans
@@ -300,24 +286,16 @@ class _CompileEvents:
 _COMPILES = _CompileEvents()
 
 
-def _host_state(state) -> dict:
-    """Params and AdaGrad accumulators as host ``(w, b)`` lists."""
-    import jax
-    params, accum = jax.device_get(
-        (state.params["layers"], state.opt_state["accum"]["layers"]))
-    return {"params": [(p["w"], p["b"]) for p in params],
-            "accum": [(a["w"], a["b"]) for a in accum]}
-
-
-def _chunk_wrapper(fn, engine, probe: Probe):
-    """The engine's jitted chunk, with the window's boundaries."""
+def _chunk_wrapper(fn, engine, probe: Probe, host_state):
+    """The engine's jitted chunk, with the window's boundaries;
+    ``host_state`` is the family's."""
     import jax
 
     def chunk(carry, placed, lr, capture=False):
         steps = int(jax.tree.leaves(placed)[0].shape[0])
         staged = probe.produced - (probe.consumed + 1)
         if probe.consumed == 0:
-            before = _host_state(engine.strategy.state_of(carry))
+            before = host_state(engine.strategy.state_of(carry))
         out = fn(carry, placed, lr, capture)
         probe.consumed += 1
         probe.losses.append((probe.steps, out[1]["loss/total"]))
@@ -326,7 +304,7 @@ def _chunk_wrapper(fn, engine, probe: Probe):
             if probe.consumed == 1:
                 jax.block_until_ready(out)
                 probe.marks["first_chunk"] = time.perf_counter()
-                after = _host_state(engine.strategy.state_of(out[0]))
+                after = host_state(engine.strategy.state_of(out[0]))
                 probe.first = {
                     "params0": before["params"], "params": after["params"],
                     "accum": after["accum"],
@@ -367,16 +345,17 @@ def memory_peak_bytes() -> int | None:
 
 
 @contextlib.contextmanager
-def instrument(exp, probe: Probe):
+def instrument(exp, probe: Probe, family):
     """Put the probe's wrappers around the program's calls for one run."""
-    import repro.train.trainer as trainer
     from repro.api.registry import STRATEGY
     from repro.train.engine import Engine
 
     strategy_cls = STRATEGY.get(exp._strategy())
     orig_init = Engine.__init__
     orig_place = strategy_cls.place_batch
-    orig_eval = trainer.evaluate_dnn
+    module_name, eval_name = family.EVAL
+    eval_module = importlib.import_module(module_name)
+    orig_eval = getattr(eval_module, eval_name)
     orig_pipeline = exp.pipeline
     stream = orig_pipeline.stream
     k = exp.config.train.n_workers
@@ -386,7 +365,8 @@ def instrument(exp, probe: Probe):
 
     def init(self, *a, **kw):
         orig_init(self, *a, **kw)
-        self._chunk_fn = _chunk_wrapper(self._chunk_fn, self, probe)
+        self._chunk_fn = _chunk_wrapper(self._chunk_fn, self, probe,
+                                        family.host_state)
 
     def host_chunks(self, *a, **kw):
         # One span per chunk the producer groups: its steps' assembly (the
@@ -435,7 +415,7 @@ def instrument(exp, probe: Probe):
     Engine.__init__ = init
     Engine._host_chunks = host_chunks
     strategy_cls.place_batch = place
-    trainer.evaluate_dnn = evaluate
+    setattr(eval_module, eval_name, evaluate)
     exp.pipeline = pipeline
     try:
         yield
@@ -443,7 +423,7 @@ def instrument(exp, probe: Probe):
         Engine.__init__ = orig_init
         Engine._host_chunks = orig_host_chunks
         strategy_cls.place_batch = orig_place
-        trainer.evaluate_dnn = orig_eval
+        setattr(eval_module, eval_name, orig_eval)
         exp.pipeline = orig_pipeline
 
 
@@ -473,11 +453,11 @@ class RunRecord:
     pad_rows: int = 0
 
 
-def load_module(kind: str, name: str):
-    """``bench/<kind>/<name>.py`` (a reference or a metric reader), found
-    by name; names may hold dots, so it is loaded from its path."""
-    import importlib.util
-    path = os.path.join(BENCH_DIR, kind, name + ".py")
+def load_module(kind: str, name: str, bench_dir: str = BENCH_DIR):
+    """``<bench_dir>/<kind>/<name>.py`` (a family, a reference or a metric
+    reader), found by name; names may hold dots, so it is loaded from its
+    path."""
+    path = os.path.join(bench_dir, kind, name + ".py")
     spec = importlib.util.spec_from_file_location(
         f"bench_{kind}_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
@@ -530,13 +510,11 @@ def pin_pad(exp, rows: int | None):
 def drive(cell: Cell, seed: int, probe: Probe) -> dict:
     """Build the cell's experiment and train it under the probe until the
     probe ends the run; every program thread is joined on return."""
-    import jax
-
-    import repro.train.trainer as trainer
     from repro.api import Experiment
 
-    cfg = experiment_config(cell.config, cell.traffic, seed)
-    probe.prefetch = cfg.execution.prefetch
+    cfg = cell.family.experiment_config(cell.config, cell.traffic,
+                                        seeds_of(seed))
+    probe.prefetch = cell.traffic["prefetch"]
     _COMPILES.listen(probe)
     exp = Experiment(cfg)
     with probe.spans.span("build"):
@@ -548,16 +526,9 @@ def drive(cell: Cell, seed: int, probe: Probe) -> dict:
             "n_meta": exp.plan.n_meta}
     log(f"bench: built n={exp.corpus.n} n_meta={info['n_meta']} "
         f"pad={info['pad']} in {build_s:.3f} s")
-    # The held-out eval jits anew on every call: warm its program once so
-    # that the window's evals load it from the persistent cache.
-    dims = ([cfg.data.input_dim] + [cfg.train.hidden_dim]
-            * cfg.train.n_hidden + [cfg.data.n_classes])
-    zeros = {"layers": [{"w": np.zeros((a, b), np.float32),
-                         "b": np.zeros((b,), np.float32)}
-                        for a, b in zip(dims[:-1], dims[1:])]}
-    trainer.evaluate_dnn(zeros, *exp.eval_data)
+    cell.family.warm_eval(exp)
     probe.marks["eval_warm"] = time.perf_counter()
-    with instrument(exp, probe):
+    with instrument(exp, probe, cell.family):
         try:
             exp.run()
         except WindowClosed:
@@ -576,72 +547,6 @@ def drive(cell: Cell, seed: int, probe: Probe) -> dict:
     info["replans_swapped"] = exp.pipeline.stream.swaps
     info["corpus"] = exp.corpus
     return info
-
-
-def reference_numbers(cell: Cell, seed: int, first_chunk: dict,
-                      first: dict, detail: bool = False, **variant) -> dict:
-    """The program's first chunk (``first``: losses, regularizer values and
-    states) against the reference over the same host batches; ``variant``
-    (keyword arguments of the reference's ``run_steps``: a control's
-    arithmetic or a ``fault``) puts the reference so computed in the
-    program's place instead and compares that with the reference."""
-    from correctness import compare
-    ref = load_module("references", cell.config["reference"])
-    lr = cell.config["base_lr"] * cell.config["n_workers"]
-    model_seed = seeds_of(seed)["model"]
-    ref_out = ref.run_steps(cell.config, model_seed, first_chunk, lr=lr)
-    if variant:
-        first = ref.run_steps(cell.config, model_seed, first_chunk, lr=lr,
-                              **variant)
-    return compare(first, ref_out, detail=detail)
-
-
-def kernel_check(cell: Cell, seed: int, first_chunk: dict,
-                 **variant) -> dict:
-    """The program's regularizer entry (``graph_regularizer`` under the
-    cell's ``pairwise``: the kernels the timed step calls, at its shapes)
-    on every block of the first chunk, against the reference's regularizer
-    of the same log-probabilities (the reference's, at the seed's
-    parameters) and masked block; ``variant`` (keyword arguments of the
-    reference's ``regularizer_value_and_grad``) puts the reference so
-    computed in the kernels' place instead."""
-    import jax
-
-    from correctness import kernel_numbers
-    from repro.core.ssl_loss import graph_regularizer
-    ref = load_module("references", cell.config["reference"])
-    cfg = cell.config
-    hyper = {"gamma": cfg["gamma"], "kappa": cfg["kappa"]}
-    program = jax.jit(jax.value_and_grad(functools.partial(
-        graph_regularizer, pairwise=cell.traffic["pairwise"], **hyper)))
-    model_seed = seeds_of(seed)["model"]
-    pairs = []
-    S, k = first_chunk["x"].shape[:2]
-    for t in range(S):
-        for w in range(k):
-            v = np.asarray(first_chunk["valid"][t, w], np.float32)
-            W = jax.numpy.asarray(np.asarray(first_chunk["W"][t, w])
-                                  * v[:, None] * v[None, :])
-            logp = ref.seed_logp(cfg, model_seed, first_chunk["x"][t, w])
-            want = ref.regularizer_value_and_grad(logp, W, **hyper)
-            got = (ref.regularizer_value_and_grad(logp, W, **hyper,
-                                                  **variant)
-                   if variant else program(logp, W))
-            pairs.append(jax.device_get((got, want)))
-    return kernel_numbers(pairs)
-
-
-def batch_check(cell: Cell, info: dict, first_chunk: dict) -> dict:
-    """The first chunk's batches against the corpus and the reference's own
-    k-NN graph of its features, and the replans the run owed."""
-    from correctness import batch_numbers
-    ref = load_module("references", cell.config["reference"])
-    corpus = info["corpus"]
-    W_ref = ref.knn_rbf_graph(corpus.X, cell.config["k_neighbours"])
-    out = batch_numbers(first_chunk, corpus.X, corpus.y, corpus.label_mask,
-                        W_ref)
-    out["replans_kept"] = info["replans_due"] - info["replans_swapped"]
-    return out
 
 
 def run_cell(cell: Cell, *, seed: int, seconds: float, traced: bool,
@@ -690,9 +595,9 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, traced: bool,
     result = {"correct": False, "attempted": probe.window_steps,
               "failed": failed}
     t_ref = time.perf_counter()
-    numbers = reference_numbers(cell, seed, first_chunk, first)
-    numbers.update(kernel_check(cell, seed, first_chunk))
-    numbers.update(batch_check(cell, info, first_chunk))
+    numbers = cell.family.reference_numbers(cell, seed, first_chunk, first)
+    numbers.update(cell.family.kernel_check(cell, seed, first_chunk))
+    numbers.update(cell.family.batch_check(cell, info, first_chunk))
     info.pop("corpus")
     log(f"bench: reference check {time.perf_counter() - t_ref:.3f} s; "
         f"replans due {info['replans_due']}, swapped "

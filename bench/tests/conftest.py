@@ -22,18 +22,8 @@ os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
 
 
 def shrink(cell):
-    """A cell cut to a size the CPU runs in seconds: its own limits and
-    traffic kinds, a host-built graph, narrower layers.  Four 256-wide
-    layers and a 16-step first chunk are the least at which the control
-    (float8 matmul operands) drifts past the limits as it does at the
-    paper's widths; at two 64-wide layers and two steps it does not."""
-    cell.config.update(n_frames=4096, input_dim=32, hidden_dim=256,
-                       n_hidden=4, n_classes=39, graph_construction="host")
-    # The CPU multiplies float32 operands in float32, not in one bfloat16
-    # pass as a TPU does at JAX's default precision.
-    cell.config["dnn_operands"] = "float32"
-    cell.traffic.update(batch_size=128, pad_rows=384)
-    return cell
+    """A cell cut to a size the CPU runs in seconds, by its family."""
+    return cell.family.shrink(cell)
 
 
 @pytest.fixture

@@ -43,17 +43,17 @@ def test_sound_program_passes_and_control_fails(tiny_cell, seed):
     cell = tiny_cell()
     chunk, first = _first_chunk(cell, seed)
     limits = cell.limits["limits"]
-    numbers = harness.reference_numbers(cell, seed, chunk, first)
-    numbers.update(harness.kernel_check(cell, seed, chunk))
+    numbers = cell.family.reference_numbers(cell, seed, chunk, first)
+    numbers.update(cell.family.kernel_check(cell, seed, chunk))
     ok, lines = judge({k: v for k, v in numbers.items() if k in limits},
                       {k: v for k, v in limits.items() if k in numbers})
     assert ok, lines
     ref = harness.load_module("references", cell.config["reference"])
     for name, kw in ref.CONTROLS.items():
-        control = harness.reference_numbers(cell, seed, chunk, first,
-                                            **kw["step"])
-        control.update(harness.kernel_check(cell, seed, chunk,
-                                            **kw["kernel"]))
+        control = cell.family.reference_numbers(cell, seed, chunk, first,
+                                                **kw["step"])
+        control.update(cell.family.kernel_check(cell, seed, chunk,
+                                                **kw["kernel"]))
         assert not judge({k: v for k, v in control.items() if k in limits},
                          {k: v for k, v in limits.items()
                           if k in control})[0], (name, control)

@@ -19,7 +19,9 @@ READERS = ("feed_wait_ms", "densify_ms", "chunk_copy_ms", "place_ms",
 
 
 def _rec(trace_dir="t", steps=4, window=(100.0, 200.0)):
+    family = harness.load_module("families", "dnn_ssl")
     return types.SimpleNamespace(
+        cell=types.SimpleNamespace(family=family),
         trace=object(), trace_window=window,
         probe=types.SimpleNamespace(trace_dir=trace_dir,
                                     window_steps=steps))
